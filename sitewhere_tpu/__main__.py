@@ -563,11 +563,8 @@ def cmd_check(_args) -> int:
 
     print(f"sitewhere-tpu {sitewhere_tpu.__version__}")
     ok = True
-    if native.available():
-        print("native host runtime: ok (libswt_host.so)")
-    else:
-        # pure-Python fallback is a supported mode, not a failure
-        print(f"native host runtime: fallback ({native.build_error()})")
+    # pure-Python fallback is a supported mode, not a failure
+    print(f"native host runtime: {native.load_report()}")
     try:
         import jax
 
@@ -744,6 +741,11 @@ def main(argv=None) -> int:
             child_argv.append(item)
         return supervise_serve(child_argv,
                                backoff_s=args.supervise_backoff)
+    # the supervising parent above never touches JAX (its child holds the
+    # chip); every command that may compile places the cache first
+    from sitewhere_tpu.runtime.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     return args.fn(args)
 
 

@@ -25,10 +25,12 @@ _SO = os.path.join(_DIR, "libswt_host.so")
 
 LIB: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
+_compiled = False  # this process built the library from host_runtime.cc
 
 
 def _build() -> Optional[str]:
     """Compile the shared library if missing/stale; returns error or None."""
+    global _compiled
     try:
         if (os.path.exists(_SO)
                 and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
@@ -40,6 +42,7 @@ def _build() -> Optional[str]:
         if proc.returncode != 0:
             return proc.stderr[-2000:]
         os.replace(tmp, _SO)
+        _compiled = True
         return None
     except (OSError, subprocess.SubprocessError) as exc:
         return str(exc)
@@ -150,6 +153,15 @@ def available() -> bool:
 
 def build_error() -> Optional[str]:
     return _build_error
+
+
+def load_report() -> str:
+    """One line for operators: whether the library in use was loaded as
+    found or built here from host_runtime.cc, or why it is unavailable."""
+    if LIB is None:
+        return f"fallback ({_build_error})"
+    how = "built here from host_runtime.cc" if _compiled else "loaded as found"
+    return f"ok ({how}: {_SO})"
 
 
 def join_tokens(tokens) -> Tuple[bytes, np.ndarray]:

@@ -68,7 +68,7 @@ def empty_batch(batch_size: int) -> EventBatch:
 
 # Wire-blob layout v2: the host->device staging format is ONE contiguous
 # int32 array of WIRE_ROWS rows per batch ([5, B]; [S, 5, B] routed).
-# Host->device bandwidth is the pipeline's hard ceiling (HBM/PCIe/tunnel —
+# Host->device bandwidth is the pipeline's hard ceiling (the host link —
 # SURVEY.md north star analysis), so the wire format is minimized:
 # 20 B/event instead of the 48 B of one row per EventBatch column. The two
 # payload rows are unions discriminated by event_type — a measurement's
@@ -177,8 +177,8 @@ def batch_to_blob(batch: EventBatch,
                   wire_rows: Optional[int] = None) -> np.ndarray:
     """Pack an EventBatch into the compact wire blob (host side, numpy).
 
-    A single transfer instead of 12 (remote/tunneled runtimes pay a
-    round-trip per device_put), at 20 B/event instead of 48. Payload
+    A single transfer instead of 12 (every device_put pays its own
+    round trip), at 20 B/event instead of 48. Payload
     fields are preserved per event type (see layout comment); a
     well-formed batch — anything the packer/decoders produce — round-trips
     exactly.

@@ -221,11 +221,6 @@ def build_device_route_program(mesh, n_shards: int, per_shard_batch: int,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _shard_map
-
     from sitewhere_tpu.parallel.mesh import SHARD_AXIS
 
     cap = capacity or route_lane_capacity(per_shard_batch, n_shards)
@@ -237,8 +232,4 @@ def build_device_route_program(mesh, n_shards: int, per_shard_batch: int,
 
     specs = dict(mesh=mesh, in_specs=P(None, SHARD_AXIS),
                  out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)))
-    try:
-        mapped = _shard_map(route, check_vma=False, **specs)
-    except TypeError:  # older jax spells it check_rep
-        mapped = _shard_map(route, check_rep=False, **specs)
-    return jax.jit(mapped)
+    return jax.jit(jax.shard_map(route, check_vma=False, **specs))

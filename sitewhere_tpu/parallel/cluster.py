@@ -87,11 +87,6 @@ import msgpack
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from sitewhere_tpu.model.common import now_ms
 # the LWW stamp + host-independent content digest are the shared
 # replication core — ONE implementation (multitenant/replication.py)
@@ -145,7 +140,7 @@ class ClusterControl:
         def tally(flags):  # per-shard block [1, 1]
             return jax.lax.psum(flags[0, 0], SHARD_AXIS)
 
-        self._prog = jax.jit(_shard_map(
+        self._prog = jax.jit(jax.shard_map(
             tally, mesh=mesh, in_specs=P(SHARD_AXIS), out_specs=P()))
 
     def vote(self, flag: bool) -> int:

@@ -34,11 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from sitewhere_tpu.analytics.windows import WindowedStats, _windowed_stats_impl
 from sitewhere_tpu.parallel.mesh import SHARD_AXIS
 
@@ -102,15 +97,12 @@ def process_shard_indices(mesh: Mesh) -> np.ndarray:
 
 # -- window-sharded analytics -------------------------------------------------
 
-def _combine_ring(stats: WindowedStats, axis: str,
-                  size: Optional[int] = None) -> WindowedStats:
+def _combine_ring(stats: WindowedStats, axis: str) -> WindowedStats:
     """Ring all-reduce of partial stat grids via ppermute: S-1 steps, each
     passing the accumulated grid to the right neighbor. Communication
     pattern of ring attention (neighbor-only ICI hops), applied to the
-    stream-window analog. `size` is the static mesh axis size (callers
-    under shard_map pass it; jax.lax.axis_size only exists on jax >= 0.6)."""
-    if size is None:
-        size = jax.lax.axis_size(axis)
+    stream-window analog."""
+    size = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % size) for i in range(size)]
 
     def step(_, carry):
@@ -191,10 +183,7 @@ def _compiled_sharded_stats(mesh: Mesh, combine: str, num_keys: int,
     """One jitted executable per (mesh, combine, grid shape) — same static-
     shape bucketing contract as analytics.windows._compiled_stats, so
     repeated replays reuse the compiled program instead of retracing."""
-    from functools import partial as _partial
-
-    combiner = (_combine_psum if combine == "psum" else
-                _partial(_combine_ring, size=mesh.shape[SHARD_AXIS]))
+    combiner = _combine_psum if combine == "psum" else _combine_ring
 
     def shard_fn(k, t, v, m, w):
         local = _windowed_stats_impl(k[0], t[0], v[0], m[0], w,
@@ -207,10 +196,6 @@ def _compiled_sharded_stats(mesh: Mesh, combine: str, num_keys: int,
                   P(SHARD_AXIS), P()),
         out_specs=WindowedStats(count=P(), sum=P(), mean=P(), min=P(),
                                 max=P()))
-    try:
-        # the ring combine's replication is a loop invariant the checker
-        # cannot infer statically
-        mapped = _shard_map(shard_fn, check_vma=False, **specs)
-    except TypeError:  # older jax spells it check_rep
-        mapped = _shard_map(shard_fn, check_rep=False, **specs)
-    return jax.jit(mapped)
+    # the ring combine's replication is a loop invariant the checker
+    # cannot infer statically
+    return jax.jit(jax.shard_map(shard_fn, check_vma=False, **specs))

@@ -27,11 +27,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from sitewhere_tpu.model import DeviceAlert
 from sitewhere_tpu.ops.pack import EventBatch, blob_to_batch
 from sitewhere_tpu.runtime.bus import jittered
@@ -609,15 +604,11 @@ class ShardedPipelineEngine(PipelineEngine):
                          out_specs=(state_specs, rule_state_specs,
                                     model_state_specs,
                                     actuation_state_specs, out_specs))
-            try:
-                # the geofence containment scan's carry is replicated
-                # only through the psum at the end of the step — a loop
-                # invariant the replication checker cannot infer
-                # statically (same workaround as
-                # parallel/distributed.py's ring combine)
-                mapped = _shard_map(fn, check_vma=False, **specs)
-            except TypeError:  # older jax spells it check_rep
-                mapped = _shard_map(fn, check_rep=False, **specs)
+            # the geofence containment scan's carry is replicated only
+            # through the psum at the end of the step — a loop invariant
+            # the replication checker cannot infer statically (same as
+            # parallel/distributed.py's ring combine)
+            mapped = jax.shard_map(fn, check_vma=False, **specs)
             return jax.jit(mapped, donate_argnums=(1, 2, 3, 4))
 
         self._sharded_step = build(sharded, blob_specs)
@@ -1757,6 +1748,11 @@ class ShardedPipelineEngine(PipelineEngine):
                 self._materialize_routed(routed, outputs))
             steps += 1
         return steps
+
+    def drain_parked(self) -> List[DeviceAlert]:
+        if not self.is_multiprocess:  # lockstep hosts drain per tick
+            self.drain_pending()
+        return super().drain_parked()
 
     def _stash_pending_alerts(self, alerts: List[DeviceAlert]) -> None:
         """Bounded-room stash shared by submit()'s internal drain and

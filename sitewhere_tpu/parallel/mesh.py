@@ -7,7 +7,6 @@ the same axis (a tenant's devices spread over all shards, stats psum'd).
 
 from __future__ import annotations
 
-import logging
 from typing import Optional, Sequence
 
 import jax
@@ -17,39 +16,20 @@ from jax.sharding import Mesh
 SHARD_AXIS = "shard"
 
 
-def _cpu_requested() -> bool:
-    """True when this process asked jax for the cpu platform (env var or
-    config) — the only situation in which substituting virtual CPU devices
-    for a too-small default-device list is what the caller meant."""
-    import os
-
-    want = (os.environ.get("JAX_PLATFORMS", "")
-            + (jax.config.jax_platforms or ""))
-    return "cpu" in want
-
-
 def make_mesh(n_shards: Optional[int] = None,
               devices: Optional[Sequence] = None) -> Mesh:
+    """A 1-D mesh over the first `n_shards` of `devices` (default: every
+    device of the default backend). Too few devices is an error — the
+    mesh never moves to another platform behind the caller's back. Tests
+    run on virtual CPU devices by asking for them
+    (`JAX_PLATFORMS=cpu` plus
+    `XLA_FLAGS=--xla_force_host_platform_device_count=N`)."""
     devs = list(devices) if devices is not None else jax.devices()
     if n_shards is not None:
-        if n_shards > len(devs) and devices is None and _cpu_requested():
-            # Some TPU plugins ignore JAX_PLATFORMS=cpu (jax.devices() still
-            # returns the accelerator); the forced host-platform devices are
-            # still present on the cpu backend. The fallback engages ONLY
-            # when the caller asked for cpu (env or config) and the plugin
-            # ignored it — a production accelerator host with too few chips
-            # still fails fast below rather than silently running on CPU.
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n_shards:
-                logging.getLogger("sitewhere.parallel").warning(
-                    "make_mesh: only %d default-backend device(s) for %d "
-                    "shards; falling back to %d virtual CPU devices",
-                    len(devs), n_shards, len(cpu))
-                devs = cpu
         if n_shards > len(devs):
             raise ValueError(
-                f"requested {n_shards} shards, have {len(devs)} devices "
-                f"(cpu backend has {len(jax.devices('cpu'))})")
+                f"requested {n_shards} shards, have {len(devs)} "
+                f"{devs[0].platform} devices")
         devs = devs[:n_shards]
     return Mesh(np.asarray(devs), (SHARD_AXIS,))
 

@@ -15,7 +15,7 @@ boundary instead of the datastore):
 
 Throughput becomes max(host_stage_time, device_step_time) instead of their
 sum. With 2+ stagers, pack of batch N+2 also overlaps the (possibly
-synchronous, on tunneled runtimes) transfer of batch N+1.
+synchronous) transfer of batch N+1.
 
 Ordering: steps are dispatched strictly in submission order (sequence
 numbers; the step thread waits for the next sequence), so per-device event
@@ -332,8 +332,8 @@ class ShardedPipelinedSubmitter:
     """Stage-ahead feeder for the ShardedPipelineEngine.
 
     The sharded submit() serializes route -> device_put -> dispatch on
-    the caller thread; under a tunneled runtime the H2D staging alone can
-    dwarf the device step, leaving the mesh idle between submits. This
+    the caller thread; the H2D staging alone can dwarf the device
+    step, leaving the mesh idle between submits. This
     feeder applies the same double-buffered discipline PipelinedSubmitter
     gives the single-chip engine, adapted to the sharded path's extra
     invariant — ROUTING IS STATEFUL (it consumes and produces the

@@ -280,15 +280,24 @@ class InboundProcessingService(LifecycleComponent):
             pairs = (self.engine.submit_routed(batch)
                      for batch in self.engine.packer.pack_events(events,
                                                                  tokens))
+        persist = (self.persist_rule_alerts and self.events is not None
+                   and not suppress_effects)
         for batch, outputs in pairs:
-            if not self.persist_rule_alerts or self.events is None \
-                    or suppress_effects:
+            if persist:
+                self._persist_alerts(
+                    self.engine.materialize_alerts(batch, outputs))
+        if persist and self.batcher is None:
+            # rows the engine parked for a later step (sharded shard
+            # overflow) fold before this batch commits, and their alerts
+            # persist with it — nothing waits on traffic that may never come
+            self._persist_alerts(self.engine.drain_parked())
+
+    def _persist_alerts(self, alerts) -> None:
+        for alert in alerts:
+            device = self.registry.get_device_by_token(alert.device_id)
+            if device is None:
                 continue
-            for alert in self.engine.materialize_alerts(batch, outputs):
-                device = self.registry.get_device_by_token(alert.device_id)
-                if device is None:
-                    continue
-                assignment = self.registry.get_active_assignment(device.id)
-                if assignment is None:
-                    continue
-                self.events.add_alerts(assignment.token, alert)
+            assignment = self.registry.get_active_assignment(device.id)
+            if assignment is None:
+                continue
+            self.events.add_alerts(assignment.token, alert)

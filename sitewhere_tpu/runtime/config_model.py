@@ -128,8 +128,7 @@ def pipeline_model() -> ElementModel:
             _attr("presence_missing_interval_ms", _I,
                   default=8 * 60 * 60 * 1000,
                   description="DevicePresenceManager missing interval"),
-            _attr("geofence_impl", choices=["auto", "xla", "pallas",
-                                            "pallas_interpret"],
+            _attr("geofence_impl", choices=["auto", "xla", "pallas"],
                   default="auto"),
             _attr("shards", _I, default=1,
                   description="mesh size for ShardedPipelineEngine"),

@@ -36,6 +36,8 @@ class TestModelShape:
         geo = next(a for a in pipeline["attributes"]
                    if a["name"] == "geofence_impl")
         assert "pallas" in geo["choices"]
+        # interpret mode is a test harness, never a deployment choice
+        assert "pallas_interpret" not in geo["choices"]
 
 
 class TestValidation:

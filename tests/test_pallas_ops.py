@@ -54,6 +54,21 @@ def test_pallas_containment_odd_shapes():
     np.testing.assert_array_equal(got, ref)
 
 
+@pytest.mark.parametrize("Z,block_z", [(2100, 512), (600, 256)])
+def test_pallas_containment_tiles_the_zone_axis(Z, block_z):
+    """Z beyond one zone tile (and beyond the 2,048 zones the untiled
+    kernel's whole-table VMEM layout could hold): several zone tiles,
+    each with its own block of the edge tables, bit-identical to XLA."""
+    lat, lon, verts = _random_world(11, B=600, Z=Z, V=6)
+    ref = np.asarray(points_in_zones(jnp.asarray(lat), jnp.asarray(lon),
+                                     jnp.asarray(verts)))
+    got = np.asarray(points_in_zones_pallas(
+        jnp.asarray(lat), jnp.asarray(lon), jnp.asarray(verts),
+        block_z=block_z, interpret=True))
+    assert got.shape == (600, Z) and ref.any()
+    np.testing.assert_array_equal(got, ref)
+
+
 def test_resolve_geofence_impl():
     assert resolve_geofence_impl("auto", "tpu") == "pallas"
     assert resolve_geofence_impl("auto", "cpu") == "xla"
