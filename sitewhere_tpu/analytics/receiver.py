@@ -40,7 +40,8 @@ class EventStreamReceiver(LifecycleComponent):
         self._host = ConsumerHost(
             bus, naming.inbound_enriched_events(tenant),
             group_id=group_id or f"stream-receiver-{tenant}",
-            handler=self._process, max_records=max_batch)
+            handler=self._process, max_records=max_batch,
+            label="stream-receiver")
 
     def on_start(self, monitor) -> None:
         self._host.start()

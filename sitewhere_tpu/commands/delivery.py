@@ -97,7 +97,8 @@ class CommandDeliveryService(LifecycleComponent):
         self.undelivered_counter = m.counter("undelivered")
         self._host = ConsumerHost(
             bus, self.naming.inbound_enriched_command_invocations(tenant),
-            group_id=f"command-delivery-{tenant}", handler=self._process)
+            group_id=f"command-delivery-{tenant}", handler=self._process,
+            label="command-delivery")
 
     # -- wiring ------------------------------------------------------------
     def add_destination(self, destination: CommandDestination) -> None:

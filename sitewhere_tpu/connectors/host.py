@@ -43,7 +43,8 @@ class OutboundConnectorHost(LifecycleComponent):
         self._host = ConsumerHost(
             bus, self.naming.inbound_enriched_events(tenant),
             group_id=f"connector-{connector.connector_id}-{tenant}",
-            handler=self.process)
+            handler=self.process,
+            label=f"connector-{connector.connector_id}")
 
     def on_start(self, monitor) -> None:
         self._host.start()

@@ -28,6 +28,7 @@ from sitewhere_tpu.model.event import (
     DeviceEventBatch, DeviceEventContext, DeviceEventType, DeviceLocation,
     DeviceMeasurement, DeviceStateChange, DeviceStreamData)
 from sitewhere_tpu.persist.eventlog import ColumnarEventLog, EventFilter
+from sitewhere_tpu.runtime.flight import NO_CYCLE
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 
 
@@ -124,9 +125,22 @@ class DeviceEventManagement(LifecycleComponent):
     def _persist(self, assignment_token: str,
                  events: Sequence[DeviceEvent]) -> List[DeviceEvent]:
         ctx = self._context_for_assignment(assignment_token)
-        stamped = [self._stamp(ev, ctx) for ev in events]
-        self.log.append_events(self.tenant, stamped, self.device_interner)
-        self._fire(list(stamped))
+        return self._store([self._stamp(ev, ctx) for ev in events])
+
+    def _store(self, stamped: List[DeviceEvent],
+               cycle=NO_CYCLE) -> List[DeviceEvent]:
+        """Append stamped events to the log, then fire the triggers."""
+        cycle.open("persist.append")
+        try:
+            self.log.append_events(self.tenant, stamped,
+                                   self.device_interner)
+        finally:
+            cycle.close("persist.append")
+        cycle.open("persist.fanout")
+        try:
+            self._fire(list(stamped))
+        finally:
+            cycle.close("persist.fanout")
         return list(stamped)
 
     # -- add rpcs ----------------------------------------------------------
@@ -161,18 +175,28 @@ class DeviceEventManagement(LifecycleComponent):
         return self._persist(assignment_token, events)  # type: ignore[return-value]
 
     def add_device_event_batch(self, device_token: str,
-                               batch: DeviceEventBatch) -> List[DeviceEvent]:
+                               batch: DeviceEventBatch,
+                               cycle=NO_CYCLE) -> List[DeviceEvent]:
         """AddDeviceEventBatch: resolve the device's active assignment, then
-        persist every event in the batch (IDeviceEventBatch flow)."""
+        persist every event in the batch (IDeviceEventBatch flow). The
+        inbound consumer passes its `cycle`, into which the persist stages
+        are marked; other callers pass none."""
         if self.registry is None:
             raise SiteWhereError("device event batch requires a registry")
-        device = self.registry.get_device_by_token(device_token)
-        if device is None:
-            raise SiteWhereError(f"unknown device: {device_token}")
-        assignment = self.registry.get_active_assignment(device.id)
-        if assignment is None:
-            raise SiteWhereError(f"device has no active assignment: {device_token}")
-        return self._persist(assignment.token, batch.all_events())
+        cycle.open("persist.context")
+        try:
+            device = self.registry.get_device_by_token(device_token)
+            if device is None:
+                raise SiteWhereError(f"unknown device: {device_token}")
+            assignment = self.registry.get_active_assignment(device.id)
+            if assignment is None:
+                raise SiteWhereError(
+                    f"device has no active assignment: {device_token}")
+            ctx = self._context_for_assignment(assignment.token)
+            stamped = [self._stamp(ev, ctx) for ev in batch.all_events()]
+        finally:
+            cycle.close("persist.context")
+        return self._store(stamped, cycle)
 
     # -- get rpcs ----------------------------------------------------------
     def get_event_by_id(self, event_id: str) -> Optional[DeviceEvent]:

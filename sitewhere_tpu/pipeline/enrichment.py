@@ -58,11 +58,11 @@ class PayloadEnrichment(LifecycleComponent):
         self.tenant = tenant
         self.naming = naming or TopicNaming()
         m = (metrics or MetricsRegistry()).scoped("enrichment")
-        self.enriched_meter = m.meter("enriched")
         self.failed_counter = m.counter("failed")
         self._host = ConsumerHost(
             bus, self.naming.inbound_persisted_events(tenant),
-            group_id=f"enrichment-{tenant}", handler=self._process)
+            group_id=f"enrichment-{tenant}", handler=self._process,
+            label="enrichment")
 
     def on_start(self, monitor) -> None:
         self._host.start()
@@ -92,4 +92,3 @@ class PayloadEnrichment(LifecycleComponent):
             self.bus.publish(enriched_topic, key, payload)
             if event.event_type == DeviceEventType.COMMAND_INVOCATION:
                 self.bus.publish(command_topic, key, payload)
-            self.enriched_meter.mark(1)

@@ -28,6 +28,7 @@ from sitewhere_tpu.model.event import (
     DeviceAlert, DeviceCommandResponse, DeviceEvent, DeviceEventBatch,
     DeviceLocation, DeviceMeasurement, DeviceStreamData, event_from_dict)
 from sitewhere_tpu.runtime.bus import ConsumerHost, EventBus, Record, TopicNaming
+from sitewhere_tpu.runtime.flight import NO_CYCLE, CycleRecord
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 from sitewhere_tpu.runtime.recovery import GLOBAL_REPLAY_BARRIER
@@ -82,20 +83,21 @@ class InboundProcessingService(LifecycleComponent):
         # single-process (direct engine submit).
         self.cluster = cluster
         m = (metrics or MetricsRegistry()).scoped("inbound")
-        self.processed_meter = m.meter("processed")
         self.unregistered_counter = m.counter("unregistered")
         self.failed_counter = m.counter("failed")
         self.dead_letter_counter = m.counter("step_dead_lettered")
         self._host = ConsumerHost(
             bus, self.naming.event_source_decoded_events(tenant),
-            group_id=f"inbound-processing-{tenant}", handler=self.process)
+            group_id=f"inbound-processing-{tenant}", handler=self.process,
+            label="inbound-processing", takes_cycle=True)
         # the reprocess loop is a first-class pipeline input (reference:
         # KafkaTopicNaming.java:48-69): records an operator replays from a
         # dead-letter topic (runtime/deadletter.py) re-enter here with the
         # same validate -> persist -> fused-step handling
         self._reprocess_host = ConsumerHost(
             bus, self.naming.inbound_reprocess_events(tenant),
-            group_id=f"inbound-reprocess-{tenant}", handler=self.process)
+            group_id=f"inbound-reprocess-{tenant}", handler=self.process,
+            label="inbound-reprocess", takes_cycle=True)
 
     def on_start(self, monitor) -> None:
         self._host.start()
@@ -106,14 +108,19 @@ class InboundProcessingService(LifecycleComponent):
         self._host.stop()
 
     # -- processing --------------------------------------------------------
-    def process(self, records: List[Record]) -> None:
+    def process(self, records: List[Record],
+                cycle: Optional[CycleRecord] = None) -> None:
         """One consumer batch end-to-end. Public so replay/tests can drive
-        it synchronously without the poll thread."""
+        it synchronously without the poll thread. `cycle` is the consumer
+        cycle this batch belongs to: each record's stages are marked into
+        it (docs/OBSERVABILITY.md, "Consumer cycles")."""
+        c = NO_CYCLE if cycle is None else cycle
         hot: List[Tuple[DeviceEvent, str]] = []
         hot_records: List[Record] = []
         forward: Dict[int, List[Record]] = {}
         replay_all: Optional[bool] = None  # every hot record suppressed?
         for record in records:
+            c.open("decode")
             try:
                 data = msgpack.unpackb(record.value, raw=False)
                 token = data.get("deviceToken", "")
@@ -122,6 +129,8 @@ class InboundProcessingService(LifecycleComponent):
             except Exception:
                 self.failed_counter.inc()
                 continue
+            finally:
+                c.close("decode")
             if self.cluster is not None:
                 # ownership routing (multi-host): records for devices whose
                 # shard lives on another host forward BEFORE persist — the
@@ -145,7 +154,12 @@ class InboundProcessingService(LifecycleComponent):
                         continue
                     forward.setdefault(owner, []).append(record)
                     continue
-            if not self._validate(token, record):
+            c.open("validate")
+            try:
+                valid = self._validate(token, record)
+            finally:
+                c.close("validate")
+            if not valid:
                 continue
             # exactly-once effects under checkpoint replay
             # (runtime/recovery.py): while this tenant's replay budget
@@ -163,14 +177,17 @@ class InboundProcessingService(LifecycleComponent):
             if suppressed:
                 persisted = list(events)
             else:
-                persisted = self._persist(token, events)
+                c.open("persist")
+                persisted = self._persist(token, events, c)
+                c.close("persist")
             if persisted:
                 hot_records.append(record)
                 replay_all = suppressed if replay_all is None \
                     else (replay_all and suppressed)
             for event in persisted:
                 hot.append((event, token))
-            self.processed_meter.mark(len(persisted))
+        if cycle is not None:
+            cycle.events = len(hot)
         if forward:
             # raises on delivery failure -> the whole batch redelivers
             # (at-least-once; locally-persisted records may duplicate,
@@ -195,7 +212,8 @@ class InboundProcessingService(LifecycleComponent):
             # offered event either materializes, parks, or is counted
             # shed, never silently lost.
             try:
-                self._submit_hot(hot, suppress_effects=bool(replay_all))
+                self._submit_hot(hot, suppress_effects=bool(replay_all),
+                                 cycle=c)
             except Exception:
                 self.failed_counter.inc()
                 LOGGER.exception("fused step failed for batch of %d events",
@@ -227,8 +245,8 @@ class InboundProcessingService(LifecycleComponent):
             return False
         return True
 
-    def _persist(self, token: str,
-                 events: List[DeviceEvent]) -> List[DeviceEvent]:
+    def _persist(self, token: str, events: List[DeviceEvent],
+                 cycle=NO_CYCLE) -> List[DeviceEvent]:
         if self.events is None:
             return events
         try:
@@ -243,7 +261,8 @@ class InboundProcessingService(LifecycleComponent):
                     batch.locations.append(event)
                 else:
                     extra.append(event)
-            persisted = self.events.add_device_event_batch(token, batch)
+            persisted = self.events.add_device_event_batch(token, batch,
+                                                           cycle=cycle)
             if extra:
                 device = self.registry.get_device_by_token(token)
                 assignment = self.registry.get_active_assignment(device.id)
@@ -261,7 +280,8 @@ class InboundProcessingService(LifecycleComponent):
             return []
 
     def _submit_hot(self, hot: List[Tuple[DeviceEvent, str]],
-                    suppress_effects: bool = False) -> None:
+                    suppress_effects: bool = False,
+                    cycle=NO_CYCLE) -> None:
         """Pack + run the fused step; rule alerts feed back into persistence
         (the reference's ZoneTestRuleProcessor -> addDeviceAlerts loop).
 
@@ -277,20 +297,49 @@ class InboundProcessingService(LifecycleComponent):
             # device state", the same contract as the direct path)
             pairs = self.batcher.offer(events, tokens).result(timeout=60.0)
         else:
-            pairs = (self.engine.submit_routed(batch)
-                     for batch in self.engine.packer.pack_events(events,
-                                                                 tokens))
+            pairs = self._stepped(events, tokens, cycle)
         persist = (self.persist_rule_alerts and self.events is not None
                    and not suppress_effects)
         for batch, outputs in pairs:
             if persist:
-                self._persist_alerts(
-                    self.engine.materialize_alerts(batch, outputs))
+                cycle.open("materialize")
+                try:
+                    alerts = self.engine.materialize_alerts(batch, outputs)
+                finally:
+                    cycle.close("materialize")
+                cycle.open("alert_persist")
+                try:
+                    self._persist_alerts(alerts)
+                finally:
+                    cycle.close("alert_persist")
         if persist and self.batcher is None:
             # rows the engine parked for a later step (sharded shard
             # overflow) fold before this batch commits, and their alerts
             # persist with it — nothing waits on traffic that may never come
-            self._persist_alerts(self.engine.drain_parked())
+            cycle.open("alert_persist")
+            try:
+                self._persist_alerts(self.engine.drain_parked())
+            finally:
+                cycle.close("alert_persist")
+
+    def _stepped(self, events: List[DeviceEvent], tokens: List[str], cycle):
+        """Pack the events into batches, then run the fused step on each
+        as the caller asks: yields (batch, outputs). `pack_events` is the
+        packer's own time, apart from the steps it feeds."""
+        cycle.open("pack_events")
+        try:
+            batches = self.engine.packer.pack_events(events, tokens)
+        finally:
+            cycle.close("pack_events")
+        for batch in batches:
+            cycle.open("step")
+            try:
+                pair = self.engine.submit_routed(batch)
+            finally:
+                cycle.close("step")
+            # the step's flight record, so the step traces back to its cycle
+            cycle.caused(getattr(self.engine, "_flight_last", None))
+            yield pair
 
     def _persist_alerts(self, alerts) -> None:
         for alert in alerts:

@@ -68,11 +68,12 @@ class RegistrationManager(LifecycleComponent):
         self.rejected_counter = m.counter("rejected")
         self._registration_host = ConsumerHost(
             bus, self.naming.inbound_device_registration_events(tenant),
-            group_id=f"registration-{tenant}", handler=self._process)
+            group_id=f"registration-{tenant}", handler=self._process,
+            label="registration")
         self._unregistered_host = ConsumerHost(
             bus, self.naming.inbound_unregistered_device_events(tenant),
             group_id=f"registration-unreg-{tenant}",
-            handler=self._process_unregistered)
+            handler=self._process_unregistered, label="registration-unreg")
 
     def on_start(self, monitor) -> None:
         self._registration_host.start()

@@ -69,12 +69,12 @@ class RuleProcessorHost(LifecycleComponent):
         self.add_nested(processor)
         m = (metrics or MetricsRegistry()).scoped(
             f"rules.{processor.processor_id}")
-        self.processed_meter = m.meter("processed")
         self.failed_counter = m.counter("failed")
         self._host = ConsumerHost(
             bus, self.naming.inbound_enriched_events(tenant),
             group_id=f"rule-processor-{processor.processor_id}-{tenant}",
-            handler=self.process)
+            handler=self.process,
+            label=f"rule-processor-{processor.processor_id}")
 
     def on_start(self, monitor) -> None:
         self._host.start()
@@ -96,7 +96,6 @@ class RuleProcessorHost(LifecycleComponent):
                 continue
             try:
                 self.processor.process(context, event)
-                self.processed_meter.mark(1)
             except Exception:
                 self.failed_counter.inc()
                 LOGGER.exception("rule processor %s failed",

@@ -32,6 +32,11 @@ import zlib
 
 from typing import Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
+from sitewhere_tpu.runtime.flight import (
+    BUS_STAGES, CYCLE_STAGES, GLOBAL_CYCLES, CycleRecord, annotation,
+    trace_enabled)
+from sitewhere_tpu.runtime.metrics import GLOBAL_METRICS
+
 
 class Record(NamedTuple):
     """One bus record. A NamedTuple, not a frozen dataclass: poll paths
@@ -642,6 +647,13 @@ class EventBus:
             t.close()
 
 
+# Buckets of the consumer-cycle histograms: a stage's summed seconds in
+# one cycle (a sub-ms commit to a multi-second handler), and its records.
+CYCLE_SECONDS_BUCKETS = (0.0001, 0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0,
+                         2.5, 5.0, 10.0)
+CYCLE_RECORDS_BUCKETS = (1, 16, 256, 1024, 4096, 16384)
+
+
 class ConsumerHost:
     """Background poll loop driving a handler with batches — the reference's
     MicroserviceKafkaConsumer single-thread poll loop (:115-121) as a
@@ -652,17 +664,44 @@ class ConsumerHost:
     a batch still failing after that is treated as deterministically
     poisonous, parks on the dead-letter topic, and offsets advance instead
     of redelivering forever. The reference parks failures the same way
-    (failed-decode / undelivered topics, KafkaTopicNaming.java:48,69)."""
+    (failed-decode / undelivered topics, KafkaTopicNaming.java:48,69).
+
+    Every poll that returns records is one cycle: a `CycleRecord` in
+    `GLOBAL_CYCLES` with its `poll`, `handler` and `commit` stages, folded
+    when it closes into the `bus.consumer_*` histograms under `label` (the
+    service's name without its tenant; the group id by default). A
+    handler built with `takes_cycle` is called as `handler(batch,
+    cycle=...)` and marks its own leaf stages, so its `handler` stage is a
+    parent and opens no profiler span."""
 
     def __init__(self, bus: EventBus, topic_name: str, group_id: str,
-                 handler: Callable[[List[Record]], None],
+                 handler: Callable[..., None],
                  max_records: int = 4096, poll_timeout_s: float = 0.2,
                  max_retries: int = 12, max_backoff_s: float = 30.0,
-                 dead_letter_topic: Optional[str] = None):
+                 dead_letter_topic: Optional[str] = None,
+                 label: Optional[str] = None, takes_cycle: bool = False):
         self._bus = bus
         self._topic_name = topic_name
         self._group_id = group_id
         self._handler = handler
+        self.label = label or group_id
+        self._takes_cycle = takes_cycle
+        self._poll_span = f"consumer.{self.label}.poll"
+        self._handler_span = (None if takes_cycle
+                              else f"consumer.{self.label}.handler")
+        self._commit_span = f"consumer.{self.label}.commit"
+        self._stage_seconds = GLOBAL_METRICS.histogram(
+            "bus.consumer_stage_seconds", CYCLE_SECONDS_BUCKETS)
+        self._cycle_records = GLOBAL_METRICS.histogram(
+            "bus.consumer_cycle_records", CYCLE_RECORDS_BUCKETS)
+        self._cpu_seconds = GLOBAL_METRICS.histogram(
+            "bus.consumer_cpu_seconds", CYCLE_SECONDS_BUCKETS)
+        # the stages this group records: the bus's three, or all of them
+        # for a handler that marks its own
+        self._stages = CYCLE_STAGES if takes_cycle else BUS_STAGES
+        # histogram children, looked up once each on first use
+        self._stage_children: Dict[int, object] = {}
+        self._label_children: Optional[Tuple[object, object]] = None
         self._max_records = max_records
         self._poll_timeout_s = poll_timeout_s
         self._max_retries = max_retries
@@ -696,6 +735,43 @@ class ConsumerHost:
             dlq.publish(record.key, record.value)
         self.dead_lettered += len(batch)
 
+    def _timed(self, cycle: CycleRecord, stage: str, span: Optional[str],
+               fn: Callable, /, *args, **kwargs) -> None:
+        """Run `fn` as one stage of `cycle`, under a profiler span when
+        the cycle is traced and the stage is a leaf."""
+        t0 = time.perf_counter()
+        try:
+            if span is not None and cycle.traced:
+                with annotation(span):
+                    fn(*args, **kwargs)
+            else:
+                fn(*args, **kwargs)
+        finally:
+            cycle.mark(stage, t0, time.perf_counter())
+
+    def _close_cycle(self, cycle: CycleRecord, cpu0: float,
+                     error: Optional[BaseException] = None) -> None:
+        """Fold the closed cycle's marks into the per-consumer histograms:
+        a few observes per cycle, none per record."""
+        cycle.cpu_s = time.thread_time() - cpu0
+        if error is not None:
+            cycle.error = type(error).__name__
+        hist, children = self._stage_seconds, self._stage_children
+        for i in range(len(cycle.stages)):
+            if cycle.begin[i] >= 0.0 and cycle.end[i] >= 0.0:
+                ch = children.get(i)
+                if ch is None:
+                    ch = children[i] = hist.child(consumer=self.label,
+                                                  stage=cycle.stages[i])
+                hist.observe_child(ch, cycle._duration(i))
+        if self._label_children is None:
+            self._label_children = (
+                self._cycle_records.child(consumer=self.label),
+                self._cpu_seconds.child(consumer=self.label))
+        records_ch, cpu_ch = self._label_children
+        self._cycle_records.observe_child(records_ch, cycle.records)
+        self._cpu_seconds.observe_child(cpu_ch, cycle.cpu_s)
+
     def _run(self) -> None:
         consumer = self._bus.consumer(self._topic_name, self._group_id)
         consumer.seek_to_committed()
@@ -706,9 +782,20 @@ class ConsumerHost:
             # or parking would dead-letter (and commit past) innocent
             # records that were never at fault.
             until = self._failing[2] if self._failing else None
-            batch = consumer.poll(self._max_records,
-                                  timeout_s=self._poll_timeout_s,
-                                  until=until)
+            traced = trace_enabled()
+            t_poll = time.perf_counter()
+            # one sequence, traced or not: fetch what is there, then wait
+            # only on an empty topic. The wait is idle time and opens no
+            # profiler span, so an idle consumer never names the device's
+            # idle gaps.
+            if traced:
+                with annotation(self._poll_span):
+                    batch = consumer.poll(self._max_records, until=until)
+            else:
+                batch = consumer.poll(self._max_records, until=until)
+            if not batch and until is None:
+                batch = consumer.poll(self._max_records,
+                                      timeout_s=self._poll_timeout_s)
             if not batch:
                 if self._failing:
                     # the failing extent yielded nothing (e.g. retention
@@ -717,11 +804,24 @@ class ConsumerHost:
                     self._failing = None
                     consumer.seek_to_committed()
                 continue
+            cycle = GLOBAL_CYCLES.begin_cycle(self.label, traced,
+                                              self._stages)
+            cycle.mark("poll", t_poll, time.perf_counter())
+            cycle.records = cycle.events = len(batch)
+            cpu0 = time.thread_time()
             try:
-                self._handler(batch)
-                self._bus.commit(consumer)
+                if self._takes_cycle:
+                    self._timed(cycle, "handler", None, self._handler,
+                                batch, cycle=cycle)
+                else:
+                    self._timed(cycle, "handler", self._handler_span,
+                                self._handler, batch)
+                self._timed(cycle, "commit", self._commit_span,
+                            self._bus.commit, consumer)
+                self._close_cycle(cycle, cpu0)
                 self._failing = None
-            except Exception:
+            except Exception as exc:
+                self._close_cycle(cycle, cpu0, exc)
                 self.errors += 1
                 fingerprint = tuple(consumer.committed)
                 if self._failing and self._failing[0] == fingerprint:

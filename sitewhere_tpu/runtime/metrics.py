@@ -9,6 +9,7 @@ timers keep a bounded reservoir for p50/p95/p99.
 
 from __future__ import annotations
 
+import bisect
 import re
 import threading
 import time
@@ -152,12 +153,15 @@ class Histogram:
     own bucket counts, ``_sum`` and ``_count``."""
 
     class _Child:
-        __slots__ = ("counts", "total", "count")
+        __slots__ = ("counts", "total", "count", "overflow")
 
-        def __init__(self, n_buckets: int) -> None:
+        def __init__(self, n_buckets: int, overflow: bool = False) -> None:
             self.counts = [0] * n_buckets  # cumulative at export, raw here
             self.total = 0.0
             self.count = 0
+            # the family's `_overflow` child: every observation into it
+            # is a spill, counted by `metrics.label_overflow`
+            self.overflow = overflow
 
     def __init__(self, buckets: Optional[tuple] = None,
                  max_children: int = MAX_LABEL_CHILDREN) -> None:
@@ -168,11 +172,13 @@ class Histogram:
         self._lock = threading.Lock()
 
     def child(self, **labels: str) -> "Histogram._Child":
+        """The child series of `labels`; past the cardinality cap, the
+        family's `_overflow` child, whose observations count as spills."""
         key = tuple(sorted(labels.items()))
-        overflowed = False
         with self._lock:
             ch = self._children.get(key)
             if ch is None:
+                overflowed = False
                 if key and len(self._children) >= self.max_children:
                     # cardinality cap: spill to the family's _overflow
                     # child (same label keys, sentinel values) instead of
@@ -181,24 +187,29 @@ class Histogram:
                     ch = self._children.get(key)
                     overflowed = True
                 if ch is None:
-                    ch = Histogram._Child(len(self.buckets))
+                    ch = Histogram._Child(len(self.buckets), overflowed)
                     self._children[key] = ch
-        if overflowed:
-            # outside self._lock; the registry lock nests independently
-            GLOBAL_METRICS.counter("metrics.label_overflow").inc()
         return ch
 
     def observe(self, seconds: float, **labels: str) -> None:
-        ch = self.child(**labels)
+        self.observe_child(self.child(**labels), seconds)
+
+    def observe_child(self, ch: "Histogram._Child", seconds: float) -> None:
+        """`observe` into a child already looked up with `child()`: a hot
+        path that observes the same label sets keeps their children and
+        skips the lookup."""
+        buckets = self.buckets
+        i = bisect.bisect_left(buckets, seconds)
         with self._lock:
             ch.total += seconds
             ch.count += 1
             # raw per-bucket counts; cumulated at export so observe is
-            # a single increment
-            for i, ub in enumerate(self.buckets):
-                if seconds <= ub:
-                    ch.counts[i] += 1
-                    break
+            # a single increment into the first bucket with seconds <= ub
+            if i < len(buckets) and seconds <= buckets[i]:
+                ch.counts[i] += 1
+        if ch.overflow:
+            # outside self._lock; the registry lock nests independently
+            GLOBAL_METRICS.counter("metrics.label_overflow").inc()
 
     def observe_buckets(self, bucket_counts, sum_value: float, count: int,
                         **labels: str) -> None:
@@ -217,6 +228,8 @@ class Histogram:
             for i, c in enumerate(bucket_counts):
                 if c and i < n:
                     counts[i] += c
+        if ch.overflow:
+            GLOBAL_METRICS.counter("metrics.label_overflow").inc()
 
     def snapshot(self) -> Dict:
         with self._lock:

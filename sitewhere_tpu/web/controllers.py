@@ -119,11 +119,14 @@ def register_all(router: Router, instance, server) -> None:
         """GET /api/instance/flight — last-N step flight records (stage
         segment timelines on one monotonic clock) + window rollups
         (per-stage occupancy, sum-vs-max sync decomposition,
-        h2d_overlap_fraction, critical-stage counts). See
+        h2d_overlap_fraction, critical-stage counts), and in `cycles` the
+        last-N bus consumer cycles with per-consumer rollups. See
         docs/OBSERVABILITY.md for the schema."""
-        from sitewhere_tpu.runtime.flight import GLOBAL_FLIGHT
-        last_n = request.query_int("last", 64)
-        return GLOBAL_FLIGHT.export(last_n=max(1, min(last_n, 256)))
+        from sitewhere_tpu.runtime.flight import GLOBAL_CYCLES, GLOBAL_FLIGHT
+        last_n = max(1, min(request.query_int("last", 64), 256))
+        out = GLOBAL_FLIGHT.export(last_n=last_n)
+        out["cycles"] = GLOBAL_CYCLES.export(last_n=last_n)
+        return out
 
     def get_cluster_telemetry(request: Request):
         """GET /api/cluster/telemetry — cluster-wide telemetry fan-in:

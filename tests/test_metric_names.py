@@ -34,11 +34,12 @@ _EXTRA_ITEM = re.compile(
 _LABELED_FAMILY = re.compile(r"\"(hbm\.[a-z_.0-9]+)\"")
 
 # every label KEY that may appear on an exported sample, anywhere —
-# labeled histogram children (engine/edge/stage/tenant), the HBM ledger's
-# table label, and the cluster fan-in's injected peer label. New label
-# keys are a cardinality decision: add them here AND document them.
+# labeled histogram children (engine/edge/stage/tenant/consumer), the
+# HBM ledger's table label, and the cluster fan-in's injected peer label.
+# New label keys are a cardinality decision: add them here AND document
+# them.
 LABEL_KEY_ALLOW = {"engine", "edge", "stage", "tenant", "table", "peer",
-                   "le", "topic"}
+                   "le", "topic", "consumer"}
 # no whitespace allowed after { or , : label BLOCKS are written tight
 # (`{table="..."` / `,peer="..."`), python kwargs are not (`, name="x"`)
 _LABEL_KEY = re.compile(r"(?:\{|,)([a-z_]+)=\\?\"")
@@ -112,3 +113,13 @@ def test_documented_stage_labels_match_flight_stages():
     missing = [s for s in STAGES if f"`{s}`" not in docs]
     assert not missing, (
         f"flight stages undocumented in OBSERVABILITY.md: {missing}")
+
+
+def test_documented_cycle_stages_match_flight_cycle_stages():
+    from sitewhere_tpu.runtime.flight import CYCLE_STAGES
+
+    docs = DOCS.read_text()
+    missing = [s for s in CYCLE_STAGES if f"`{s}`" not in docs]
+    assert not missing, (
+        f"consumer-cycle stages undocumented in OBSERVABILITY.md: "
+        f"{missing}")

@@ -26,7 +26,7 @@ from sitewhere_tpu.runtime.eventage import (
     AGE_BUCKET_EDGES_S, AGE_BUCKET_FLOOR_S, AGE_MAX_ENTRIES, N_AGE_BUCKETS,
     AgeSidecar, AgeSummary, age_histogram, bucket_index, observe_summary)
 from sitewhere_tpu.runtime.flight import FlightRecorder
-from sitewhere_tpu.runtime.metrics import MetricsRegistry
+from sitewhere_tpu.runtime.metrics import Histogram, MetricsRegistry
 from sitewhere_tpu.runtime.tracing import GLOBAL_TRACER, Tracer
 
 
@@ -487,6 +487,25 @@ class TestCardinalityGuard:
         assert len(snap) == MAX_LABEL_CHILDREN + 1
         assert GLOBAL_METRICS.counter(
             "metrics.label_overflow").value == overflow_before + 10
+
+    def test_a_kept_overflow_child_counts_every_spill(self):
+        # a hot path keeps the child it looked up once; past the cap that
+        # is the _overflow child, and each observation into it still
+        # counts as a spill
+        from sitewhere_tpu.runtime.metrics import GLOBAL_METRICS
+
+        hist = Histogram(buckets=(1.0,), max_children=2)
+        hist.observe(0.5, consumer="a")
+        hist.observe(0.5, consumer="b")
+        overflow = GLOBAL_METRICS.counter("metrics.label_overflow")
+        before = overflow.value
+        kept = hist.child(consumer="c")
+        assert overflow.value == before          # a lookup is no spill
+        for _ in range(3):
+            hist.observe_child(kept, 0.5)
+        hist.observe_buckets([1], 0.5, 1, consumer="d")
+        assert overflow.value == before + 4
+        assert hist.snapshot()[(("consumer", "_overflow"),)]["count"] == 4
 
     def test_existing_children_keep_working_after_cap(self):
         from sitewhere_tpu.runtime.metrics import MAX_LABEL_CHILDREN
