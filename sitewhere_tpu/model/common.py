@@ -143,9 +143,18 @@ class Pager(Generic[T]):
         return SearchResults(results=self._page, num_results=self._matched)
 
 
+# exact leaf types, tested first: most values a walk meets are leaves
+_LEAF_TYPES = frozenset((str, int, float, bool, bytes, type(None)))
+
+
 def _asdict(obj: Any) -> Any:
+    """Plain dict/list/scalar form of a model object (to_dict, msgpack and
+    JSON payloads), in one walk: dataclasses by field, enums by value."""
+    if type(obj) in _LEAF_TYPES:
+        return obj
     if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
-        return {k: _asdict(v) for k, v in dataclasses.asdict(obj).items()}
+        return {f.name: _asdict(getattr(obj, f.name))
+                for f in dataclasses.fields(obj)}
     if isinstance(obj, dict):
         return {k: _asdict(v) for k, v in obj.items()}
     if isinstance(obj, (list, tuple)):

@@ -16,7 +16,7 @@ a date range — EventIndex mirrors that.
 from __future__ import annotations
 
 import enum
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import msgpack
 
@@ -30,6 +30,11 @@ from sitewhere_tpu.model.event import (
 from sitewhere_tpu.persist.eventlog import ColumnarEventLog, EventFilter
 from sitewhere_tpu.runtime.flight import NO_CYCLE
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
+from sitewhere_tpu.runtime.metrics import GLOBAL_METRICS
+
+# `persist.store_events` buckets: events stored by one call (a REST add
+# stores a few, an inbound cycle up to a poll's 4,096 records' events)
+STORE_EVENTS_BUCKETS = (1, 16, 256, 1024, 4096, 16384, 65536)
 
 
 class EventIndex(enum.Enum):
@@ -85,6 +90,9 @@ class DeviceEventManagement(LifecycleComponent):
         self.tenant = tenant
         self.device_interner = device_interner
         self._listeners: List[Callable[[List[DeviceEvent]], None]] = []
+        self._store_hist = GLOBAL_METRICS.histogram(
+            "persist.store_events", STORE_EVENTS_BUCKETS)
+        self._store_child = self._store_hist.child()
 
     # -- lifecycle ---------------------------------------------------------
     def on_start(self, monitor) -> None:
@@ -127,20 +135,27 @@ class DeviceEventManagement(LifecycleComponent):
         ctx = self._context_for_assignment(assignment_token)
         return self._store([self._stamp(ev, ctx) for ev in events])
 
-    def _store(self, stamped: List[DeviceEvent],
-               cycle=NO_CYCLE) -> List[DeviceEvent]:
-        """Append stamped events to the log, then fire the triggers."""
+    def _append(self, stamped: List[DeviceEvent], cycle=NO_CYCLE) -> None:
         cycle.open("persist.append")
         try:
             self.log.append_events(self.tenant, stamped,
                                    self.device_interner)
         finally:
             cycle.close("persist.append")
+
+    def _fanout(self, stamped: List[DeviceEvent], cycle=NO_CYCLE) -> None:
         cycle.open("persist.fanout")
         try:
             self._fire(list(stamped))
         finally:
             cycle.close("persist.fanout")
+
+    def _store(self, stamped: List[DeviceEvent],
+               cycle=NO_CYCLE) -> List[DeviceEvent]:
+        """Append stamped events to the log, then fire the triggers."""
+        self._store_hist.observe_child(self._store_child, len(stamped))
+        self._append(stamped, cycle)
+        self._fanout(stamped, cycle)
         return list(stamped)
 
     # -- add rpcs ----------------------------------------------------------
@@ -175,28 +190,70 @@ class DeviceEventManagement(LifecycleComponent):
         return self._persist(assignment_token, events)  # type: ignore[return-value]
 
     def add_device_event_batch(self, device_token: str,
-                               batch: DeviceEventBatch,
-                               cycle=NO_CYCLE) -> List[DeviceEvent]:
+                               batch: DeviceEventBatch) -> List[DeviceEvent]:
         """AddDeviceEventBatch: resolve the device's active assignment, then
-        persist every event in the batch (IDeviceEventBatch flow). The
-        inbound consumer passes its `cycle`, into which the persist stages
-        are marked; other callers pass none."""
+        persist every event in the batch (IDeviceEventBatch flow): a
+        one-item `store_device_events` that raises what failed it."""
+        (stored,) = self.store_device_events(
+            [(device_token, batch.all_events())])
+        if isinstance(stored, Exception):
+            raise stored
+        return stored
+
+    def store_device_events(
+            self, items: Sequence[Tuple[str, Sequence[DeviceEvent]]],
+            cycle=NO_CYCLE) -> List[Union[List[DeviceEvent], Exception]]:
+        """Store many devices' events in one call: per (device token,
+        events) item, resolve the device's active assignment and stamp
+        its events with that context; then one log append and one
+        trigger fan-out of every stamped event, in item order. Returns,
+        per item, its stored events, or the exception that failed it:
+        an unknown or unassigned device fails its item alone, and its
+        events are neither stored nor fanned out. The inbound consumer
+        passes a whole cycle's records and its `cycle`, into which the
+        persist stages are marked; other callers pass none."""
         if self.registry is None:
             raise SiteWhereError("device event batch requires a registry")
-        cycle.open("persist.context")
+        out: List[Union[List[DeviceEvent], Exception]] = []
+        for token, events in items:
+            cycle.open("persist.context")
+            try:
+                ctx = self._device_context(token)
+                out.append([self._stamp(ev, ctx) for ev in events])
+            except Exception as exc:
+                out.append(exc)
+            finally:
+                cycle.close("persist.context")
+        stamped = [ev for evs in out if isinstance(evs, list) for ev in evs]
+        if not stamped:
+            return out
+        self._store_hist.observe_child(self._store_child, len(stamped))
         try:
-            device = self.registry.get_device_by_token(device_token)
-            if device is None:
-                raise SiteWhereError(f"unknown device: {device_token}")
-            assignment = self.registry.get_active_assignment(device.id)
-            if assignment is None:
-                raise SiteWhereError(
-                    f"device has no active assignment: {device_token}")
-            ctx = self._context_for_assignment(assignment.token)
-            stamped = [self._stamp(ev, ctx) for ev in batch.all_events()]
-        finally:
-            cycle.close("persist.context")
-        return self._store(stamped, cycle)
+            self._append(stamped, cycle)
+        except Exception:
+            # a malformed event fails the one append: append item by
+            # item, so that it fails only its own item
+            for i, evs in enumerate(out):
+                if isinstance(evs, list) and evs:
+                    try:
+                        self._append(evs, cycle)
+                    except Exception as exc:
+                        out[i] = exc
+            stamped = [ev for evs in out if isinstance(evs, list)
+                       for ev in evs]
+        if stamped:
+            self._fanout(stamped, cycle)
+        return out
+
+    def _device_context(self, device_token: str) -> DeviceEventContext:
+        device = self.registry.get_device_by_token(device_token)
+        if device is None:
+            raise SiteWhereError(f"unknown device: {device_token}")
+        assignment = self.registry.get_active_assignment(device.id)
+        if assignment is None:
+            raise SiteWhereError(
+                f"device has no active assignment: {device_token}")
+        return self._context_for_assignment(assignment.token)
 
     # -- get rpcs ----------------------------------------------------------
     def get_event_by_id(self, event_id: str) -> Optional[DeviceEvent]:
@@ -273,16 +330,20 @@ class DeviceEventManagement(LifecycleComponent):
 class EventPersistenceTriggers:
     """Forward persisted events onto the bus — KafkaEventPersistenceTriggers
     (forwardEvents :72): each persisted event goes to inbound-persisted-events,
-    keyed by device token for per-device ordering."""
+    keyed by device token for per-device ordering. One bulk publish per
+    call: one lock, durable write and wake-up per touched partition,
+    however many events the call stores."""
 
     def __init__(self, bus, naming, tenant: str = "default"):
         self.bus = bus
         self.topic = naming.inbound_persisted_events(tenant)
 
     def __call__(self, events: List[DeviceEvent]) -> None:
-        for ev in events:
-            payload = msgpack.packb(ev.to_dict(), use_bin_type=True)
-            self.bus.publish(self.topic, ev.device_id.encode(), payload)
+        if events:
+            self.bus.publish_batch(self.topic, [
+                (ev.device_id.encode(),
+                 msgpack.packb(ev.to_dict(), use_bin_type=True))
+                for ev in events])
 
     def attach(self, management: DeviceEventManagement) -> None:
         management.add_listener(self)

@@ -24,9 +24,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import msgpack
 
 from sitewhere_tpu.errors import SiteWhereError
-from sitewhere_tpu.model.event import (
-    DeviceAlert, DeviceCommandResponse, DeviceEvent, DeviceEventBatch,
-    DeviceLocation, DeviceMeasurement, DeviceStreamData, event_from_dict)
+from sitewhere_tpu.model.event import DeviceEvent, event_from_dict
 from sitewhere_tpu.runtime.bus import ConsumerHost, EventBus, Record, TopicNaming
 from sitewhere_tpu.runtime.flight import NO_CYCLE, CycleRecord
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
@@ -112,12 +110,15 @@ class InboundProcessingService(LifecycleComponent):
                 cycle: Optional[CycleRecord] = None) -> None:
         """One consumer batch end-to-end. Public so replay/tests can drive
         it synchronously without the poll thread. `cycle` is the consumer
-        cycle this batch belongs to: each record's stages are marked into
-        it (docs/OBSERVABILITY.md, "Consumer cycles")."""
+        cycle this batch belongs to: each record's decode and validate,
+        and the batch's one persist call, are marked into it
+        (docs/OBSERVABILITY.md, "Consumer cycles")."""
         c = NO_CYCLE if cycle is None else cycle
         hot: List[Tuple[DeviceEvent, str]] = []
         hot_records: List[Record] = []
         forward: Dict[int, List[Record]] = {}
+        # (record, token, events, replay-suppressed) of each valid record
+        valid: List[Tuple[Record, str, List[DeviceEvent], bool]] = []
         replay_all: Optional[bool] = None  # every hot record suppressed?
         for record in records:
             c.open("decode")
@@ -156,10 +157,10 @@ class InboundProcessingService(LifecycleComponent):
                     continue
             c.open("validate")
             try:
-                valid = self._validate(token, record)
+                ok = self._validate(token, record)
             finally:
                 c.close("validate")
-            if not valid:
+            if not ok:
                 continue
             # exactly-once effects under checkpoint replay
             # (runtime/recovery.py): while this tenant's replay budget
@@ -174,12 +175,15 @@ class InboundProcessingService(LifecycleComponent):
             if events and GLOBAL_REPLAY_BARRIER.active(self.tenant):
                 took = GLOBAL_REPLAY_BARRIER.take(self.tenant, len(events))
                 suppressed = took >= len(events)
-            if suppressed:
-                persisted = list(events)
-            else:
-                c.open("persist")
-                persisted = self._persist(token, events, c)
-                c.close("persist")
+            valid.append((record, token, events, suppressed))
+        # one persist call for the batch's records (one log append, one
+        # trigger fan-out), then `hot` in record order, as the step's
+        # last-value and count semantics need
+        stored = iter(self._persist(
+            [(token, events) for _, token, events, suppressed in valid
+             if not suppressed], c))
+        for record, token, events, suppressed in valid:
+            persisted = list(events) if suppressed else next(stored)
             if persisted:
                 hot_records.append(record)
                 replay_all = suppressed if replay_all is None \
@@ -245,39 +249,31 @@ class InboundProcessingService(LifecycleComponent):
             return False
         return True
 
-    def _persist(self, token: str, events: List[DeviceEvent],
-                 cycle=NO_CYCLE) -> List[DeviceEvent]:
-        if self.events is None:
-            return events
+    def _persist(self, items: List[Tuple[str, List[DeviceEvent]]],
+                 cycle=NO_CYCLE) -> List[List[DeviceEvent]]:
+        """Persist the batch's (device token, events) records in one call;
+        returns each record's stored events, [] for one that failed (it
+        is counted and logged, and the others go on)."""
+        if self.events is None or not items:
+            return [events for _, events in items]
+        cycle.open("persist")
         try:
-            batch = DeviceEventBatch(device_token=token)
-            extra: List[DeviceEvent] = []
-            for event in events:
-                if isinstance(event, DeviceAlert):
-                    batch.alerts.append(event)
-                elif isinstance(event, DeviceMeasurement):
-                    batch.measurements.append(event)
-                elif isinstance(event, DeviceLocation):
-                    batch.locations.append(event)
-                else:
-                    extra.append(event)
-            persisted = self.events.add_device_event_batch(token, batch,
-                                                           cycle=cycle)
-            if extra:
-                device = self.registry.get_device_by_token(token)
-                assignment = self.registry.get_active_assignment(device.id)
-                for event in extra:
-                    if isinstance(event, DeviceCommandResponse):
-                        persisted.extend(self.events.add_command_responses(
-                            assignment.token, event))
-                    else:
-                        persisted.extend(self.events.add_stream_data(
-                            assignment.token, event))
-            return persisted
+            results = self.events.store_device_events(items, cycle=cycle)
         except Exception:
-            self.failed_counter.inc()
-            LOGGER.exception("persist failed for device '%s'", token)
-            return []
+            self.failed_counter.inc(len(items))
+            LOGGER.exception("persist failed for %d records", len(items))
+            return [[] for _ in items]
+        finally:
+            cycle.close("persist")
+        out: List[List[DeviceEvent]] = []
+        for (token, _), result in zip(items, results):
+            if isinstance(result, Exception):
+                self.failed_counter.inc()
+                LOGGER.error("persist failed for device '%s'", token,
+                             exc_info=result)
+                result = []
+            out.append(result)
+        return out
 
     def _submit_hot(self, hot: List[Tuple[DeviceEvent, str]],
                     suppress_effects: bool = False,

@@ -3,7 +3,8 @@ runtime/bus.py ConsumerHost, the inbound and persist stages).
 
 Contracts: a cycle is recorded per poll that returned records, with its
 poll / handler / commit stages; the inbound handler accumulates one mark
-per record into its decode / validate / persist stages; the lossless
+per record into its decode / validate / persist.context stages, and one
+per batch into persist and its append and fan-out; the lossless
 `bus.consumer_*` histograms move by exactly what the ring's records sum
 to; persist called without a cycle records nothing; and while a
 profiler trace runs, the leaf stages (never the parents) write host
@@ -342,10 +343,12 @@ class TestInboundStages:
         out = cycle.export()["stages"]
         assert out["decode"]["n"] == 10
         assert out["validate"]["n"] == 10
-        assert out["persist"]["n"] == 8           # ghosts fail validate
-        for child in ("persist.context", "persist.append",
-                      "persist.fanout"):
-            assert out[child]["n"] == 8
+        # one persist call per cycle; a context per valid record (the
+        # ghosts fail validate)
+        assert out["persist"]["n"] == 1
+        assert out["persist.context"]["n"] == 8
+        assert out["persist.append"]["n"] == 1
+        assert out["persist.fanout"]["n"] == 1
         children = sum(out[c]["ms"] for c in (
             "persist.context", "persist.append", "persist.fanout"))
         assert children <= out["persist"]["ms"] + 1e-6
@@ -371,18 +374,18 @@ class TestInboundStages:
         assert len(stored) == 1 and stored[0].device_assignment_id == "a1"
         svc.process(_records(bus, naming, 2))
         assert persisting() == before
-        # the same call with a cycle marks the persist stages into it
+        # the store with a cycle marks the persist stages into it
         cycle = GLOBAL_CYCLES.begin_cycle("test-inbound", False)
-        events.add_device_event_batch("d2", DeviceEventBatch(
-            device_token="d2", measurements=[
-                DeviceMeasurement(name="m", value=2.0)]), cycle=cycle)
+        events.store_device_events(
+            [("d2", [DeviceMeasurement(name="m", value=2.0)])], cycle=cycle)
         assert {s: cycle.export()["stages"][s]["n"] for s in (
             "persist.context", "persist.append", "persist.fanout")} == {
             "persist.context": 1, "persist.append": 1, "persist.fanout": 1}
 
     def test_persist_stages_close_when_persist_raises(self, inbound):
-        # a device with no active assignment: persist raises after its
-        # context stage opened; the stage still closes, span and all
+        # a device with no active assignment: its context raises after
+        # the stage opened; the stage still closes, span and all, and the
+        # item fails with the error
         _svc, _bus, _naming, events, _engine = inbound
         registry = events.registry
         lone = registry.create_device(Device(
@@ -392,8 +395,9 @@ class TestInboundStages:
         cycle = GLOBAL_CYCLES.begin_cycle("test-inbound", True)
         batch = DeviceEventBatch(device_token="lone", measurements=[
             DeviceMeasurement(name="m", value=1.0)])
-        with pytest.raises(SiteWhereError):
-            events.add_device_event_batch("lone", batch, cycle=cycle)
+        [failed] = events.store_device_events(
+            [("lone", batch.all_events())], cycle=cycle)
+        assert isinstance(failed, SiteWhereError)
         assert cycle.export()["stages"]["persist.context"]["n"] == 1
         assert all(span is None for span in cycle._open)
 
@@ -467,9 +471,11 @@ def test_leaf_stages_write_host_spans_into_a_cpu_trace(inbound, tmp_path):
                  f"consumer.{label}.poll", f"consumer.{label}.handler",
                  f"consumer.{label}.commit"):
         assert leaf in names, leaf
-    # one span per record
-    for leaf in ("inbound.decode", "persist.append"):
+    # one span per record, one per persist call
+    for leaf in ("inbound.decode", "persist.context"):
         assert sum(1 for e in host_events if e[0] == leaf) >= 4
+    for leaf in ("persist.append", "persist.fanout"):
+        assert sum(1 for e in host_events if e[0] == leaf) == 1
     # parents open no span
     assert not names & {"inbound.persist", "inbound.handler", "persist",
                         "handler", "consumer.inbound-processing.handler"}

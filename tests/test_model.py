@@ -4,7 +4,13 @@ from sitewhere_tpu.model import (
     Device, DeviceAlert, DeviceAssignment, DeviceEventType, DeviceLocation,
     DeviceMeasurement, DeviceType, SearchCriteria, Zone,
 )
-from sitewhere_tpu.model.common import Location, Pager, page
+import dataclasses
+import enum
+from typing import NamedTuple
+
+import pytest
+
+from sitewhere_tpu.model.common import Location, Pager, _asdict, page
 
 
 def test_pager_pages_and_counts():
@@ -55,3 +61,54 @@ def test_zone_holds_polygon():
     zone = Zone(token="z1", bounds=[Location(0, 0), Location(0, 1), Location(1, 1)])
     assert len(zone.bounds) == 3
     assert zone.bounds[1].longitude == 1
+
+
+class _Color(enum.Enum):
+    RED = "red"
+
+
+class _Pair(NamedTuple):
+    a: int
+    b: Location
+
+
+@dataclasses.dataclass
+class _Nested:
+    where: Location
+    pairs: tuple
+    by_name: dict
+    color: _Color = _Color.RED
+    level: DeviceEventType = DeviceEventType.ALERT
+    other: object = None
+
+
+def _asdict_reference(obj):
+    """The two-walk form: dataclasses.asdict, then the leaf conversion."""
+    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+        return {k: _asdict_reference(v)
+                for k, v in dataclasses.asdict(obj).items()}
+    if isinstance(obj, dict):
+        return {k: _asdict_reference(v) for k, v in obj.items()}
+    if isinstance(obj, (list, tuple)):
+        return [_asdict_reference(v) for v in obj]
+    if isinstance(obj, (str, int, float, bool, bytes)) or obj is None:
+        return obj
+    if hasattr(obj, "value"):
+        return obj.value
+    return str(obj)
+
+
+@pytest.mark.parametrize("obj", [
+    DeviceMeasurement(name="t", value=1.5, metadata={"k": "v", "n": 2}),
+    DeviceLocation(latitude=1.0, longitude=2.0),
+    DeviceAlert(type="hot", message="m"),
+    Device(token="d", device_type_id="t", metadata={"a": "b"}),
+    _Nested(where=Location(1.0, 2.0), pairs=(_Pair(1, Location(3.0, 4.0)),),
+            by_name={"x": [Location(5.0, 6.0), b"raw", None]},
+            other=complex(1, 2)),
+], ids=["measurement", "location", "alert", "device", "nested"])
+def test_asdict_matches_the_two_walk_form(obj):
+    out = _asdict(obj)
+    assert out == _asdict_reference(obj)
+    assert [type(v) for v in out.values()] == [
+        type(v) for v in _asdict_reference(obj).values()]
