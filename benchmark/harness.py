@@ -3,23 +3,24 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (`configs/<config>.json`) and a traffic mix
-(`traffic/<traffic>.json`); every metric is computed by its own reader,
-`metrics/<name>.py`. Set-up boots the deployment as `serve` does from the
-configuration's world snapshot (provisioned first, and timed apart, where
-the checkout has none), encodes the traffic pool and sends warm-up
-traffic through the served path. The window then offers the mix from one
-commit edge to the first edge `--seconds` later; afterwards the topic is
-drained, the program's outcome is read back and compared with the plain
-reference, and one JSON line is printed last. Earlier lines carry the
-device, sample counts, warnings and each number compared beside its
-limit.
+(`traffic/<traffic>.json`); the configuration's deployment module
+(`deployments/<name>.py`) provisions its world and makes its traffic and
+reference, and every metric is computed by its own reader
+(`metrics/<name>.py`), each found by name (`loader.py`). Set-up boots the
+deployment as `serve` does from the configuration's world snapshot
+(provisioned first, and timed apart, where the checkout has none),
+encodes the traffic pool and sends warm-up traffic through the served
+path. The window then offers the mix from one commit edge to the first
+edge `--seconds` later; afterwards the topic is drained, the program's
+outcome is read back and compared with the plain reference, and one JSON
+line is printed last. Earlier lines carry the device, sample counts,
+warnings and each number compared beside its limit.
 """
 
 from __future__ import annotations
 
 import argparse
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -31,10 +32,9 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from benchmark import reference, traffic as traffic_mod, world
-from benchmark.world import BENCH_DIR, CACHE_DIR
+from benchmark import loader, traffic as traffic_mod, world
 
-ROOT = os.path.dirname(BENCH_DIR)
+ROOT = os.path.dirname(loader.BENCH_DIR)
 DRAIN_TIMEOUT_S = 120.0
 WARMUP_TIMEOUT_S = 300.0
 # how long past the nominal close the window waits for its closing edge
@@ -65,7 +65,7 @@ def configure_jax() -> str:
     import jax
 
     cache = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-             or os.path.join(CACHE_DIR, "jax"))
+             or os.path.join(world.CACHE_DIR, "jax"))
     jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -171,12 +171,28 @@ class Run:
 
 
 def load_reader(name: str):
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmark_metric_{name.replace('.', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return loader.load_module(loader.METRICS_DIR, name).read
+
+
+def warm_compact_layout(engine) -> None:
+    """Compile the fused step for the compact wire layout in set-up, with
+    one batch of padding rows, which change no state. The packer sends a
+    batch of readings in the packed layout while its dates span at most
+    2^16 ms and in the compact one past that, and the window's polls pass
+    that span once the consumer's partitions drift apart: without this
+    the step compiles inside the window. Routed (sharded) steps always
+    take the full layout."""
+    import jax
+
+    from sitewhere_tpu.ops.pack import (
+        WIRE_ROWS_COMPACT, batch_to_blob, empty_batch)
+    from sitewhere_tpu.parallel.engine import ShardedPipelineEngine
+
+    if isinstance(engine, ShardedPipelineEngine):
+        return
+    blob = batch_to_blob(empty_batch(engine.packer.batch_size),
+                         wire_rows=WIRE_ROWS_COMPACT)
+    jax.block_until_ready(engine.submit_blob(blob))
 
 
 def cell_metrics(bench: Dict, cell: str, traced: bool) -> List[Dict]:
@@ -215,14 +231,17 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float,
 
     cfg = world.load_config(cell["config"], cfg_overrides)
     mix = traffic_mod.load_mix(cell["traffic"], mix_overrides)
-    provision_s = world.ensure_snapshot(cfg, log)
-    run_dir = os.path.join(CACHE_DIR, "run", cell["name"])
+    dep = loader.deployment(cfg)
+    gen = loader.generator(mix, dep)
+    provision_s = world.ensure_snapshot(cfg, dep, log)
+    run_dir = os.path.join(world.CACHE_DIR, "run", cell["name"])
     world.fresh_data_dir(cfg, run_dir)
     compiles = CompileCounter()
-    instance, rest = world.boot(cfg, run_dir, geofence_impl)
-    trace_dir = os.path.join(CACHE_DIR, "trace", cell["name"])
+    instance, rest = world.boot(cfg, run_dir, dep.rule_dicts(cfg),
+                                geofence_impl)
+    trace_dir = os.path.join(world.CACHE_DIR, "trace", cell["name"])
     try:
-        w = world.attach(instance, cfg)
+        w = dep.attach(instance, cfg)
         engine = instance.pipeline_engine
         if fault is not None:
             fault(instance)
@@ -231,12 +250,12 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float,
         topic = instance.bus.topic(topic_name)
         group = instance.bus.consumer(topic_name,
                                       f"inbound-processing-{tenant}")
-        per = traffic_mod.events_per_record(mix)
-        n_rec = traffic_mod.pool_records(mix)
+        per = dep.events_per_record(mix)
+        n_rec = traffic_mod.pool_records(mix, per)
         warm = int(mix["warmup_records"])
-        tr = traffic_mod.make_traffic(w, mix, seed, n_rec,
-                                      engine.packer.epoch_base_ms + 1000)
-        records = traffic_mod.encode_records(w, tr, mix)
+        tr = gen.make_traffic(w, mix, seed, n_rec,
+                              engine.packer.epoch_base_ms + 1000)
+        records = gen.encode_records(w, tr, mix)
         pub = traffic_mod.Publisher(
             topic, group, records,
             backlog_records=-(-int(mix["backlog_events"]) // per))
@@ -247,8 +266,8 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float,
         pub.publish_now(warm)
         wait_for(lambda: pub.committed_prefix(group.committed) >= warm,
                  WARMUP_TIMEOUT_S, "warm-up commit")
-        log(f"world: {cfg['name']} devices={w.n} "
-            f"threshold_rules={len(cfg['threshold_rules'])} "
+        warm_compact_layout(engine)
+        log(f"world: {dep.describe(cfg)} "
             f"batch={engine.packer.batch_size} "
             f"geofence_impl={engine.geofence_impl} pool_records={n_rec} "
             f"events_per_record={per} warmup_records={warm} "
@@ -298,9 +317,9 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float,
         published = pub.next
         shown = tr.prefix(published)
         if use_control:
-            observed = reference.control(w, shown, topic, group)
+            observed = dep.control(w, shown, topic, group)
         else:
-            observed = reference.observe(instance, w, topic, group)
+            observed = dep.observe(instance, w, topic, group)
         stats = engine.stats()
         log(f"served: published_records={published} "
             f"step_retries={int(engine._retry_counter.value)} "
@@ -353,8 +372,7 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float,
                 f"{json.dumps(run.device.module_runs)[:600]}")
         else:
             log("trace: no device operation in the window")
-    want = reference.expected(w, shown)
-    compared = reference.compare(observed, want)
+    compared = dep.compare(observed, dep.expected(w, shown))
 
     metrics = {}
     for m in cell_metrics(bench, cell["name"], traced):
@@ -413,7 +431,8 @@ def main(argv, t_start: float) -> int:
     args = parse_args(argv)
     bench = load_benchmark()
     cell = find_cell(bench, args.workload)
-    os.environ.setdefault("TPU_LOG_DIR", os.path.join(CACHE_DIR, "tpu_logs"))
+    os.environ.setdefault("TPU_LOG_DIR",
+                          os.path.join(world.CACHE_DIR, "tpu_logs"))
     cache = configure_jax()
     device_report(int(cell["chips"]))
     log(f"compile cache: {cache}")

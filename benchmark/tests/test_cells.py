@@ -1,6 +1,8 @@
 """Every cell's world, traffic, window and check at a tiny size on the
 CPU: the reference agrees with the served path, the control and each
-fault of the timed path make `correct` false."""
+fault of the timed path make `correct` false. What differs between
+deployments (the numbers the control fails, the field a fault alters)
+comes from the cell's deployment module."""
 
 import dataclasses
 
@@ -8,9 +10,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from benchmark import loader, world
 from benchmark.tests import tiny
 
 CELLS = [c["name"] for c in tiny.cells()]
+
+
+def _deployment(cell):
+    config = next(c["config"] for c in tiny.cells() if c["name"] == cell)
+    return loader.deployment(world.load_config(config))
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -26,10 +34,12 @@ def test_cell_is_correct(cell):
 def test_control_is_not_correct(cell):
     result = tiny.run(cell, use_control=True)
     assert not result["correct"]
-    assert result["compared"]["last_value_mismatches"]["value"] > 0
+    for name in _deployment(cell).CONTROL_FAILS:
+        c = result["compared"][name]
+        assert c["value"] > c["limit"], name
 
 
-def _state_unchanged(instance):
+def _state_unchanged(instance, _dep):
     engine = instance.pipeline_engine
     build = engine._build_step_blob
 
@@ -52,7 +62,7 @@ def _state_unchanged(instance):
     wrap()
 
 
-def _half_left_out(instance):
+def _half_left_out(instance, _dep):
     packer = instance.pipeline_engine.packer
     pack = packer.pack_events
 
@@ -63,16 +73,19 @@ def _half_left_out(instance):
     packer.pack_events = half
 
 
-def _value_altered(instance):
+def _value_altered(instance, dep):
+    """The first event of each batch that has the deployment's
+    `FAULT_FIELD` gets 1.0 added to it."""
     packer = instance.pipeline_engine.packer
     pack = packer.pack_events
+    name = dep.FAULT_FIELD
 
     def altered(events, tokens):
         events = list(events)
         for i, event in enumerate(events):
-            if hasattr(event, "value"):
-                events[i] = dataclasses.replace(event,
-                                                value=event.value + 1.0)
+            if hasattr(event, name):
+                events[i] = dataclasses.replace(
+                    event, **{name: getattr(event, name) + 1.0})
                 break
         return pack(events, tokens)
 
@@ -86,5 +99,6 @@ FAULTS = [(cell, fault) for cell in CELLS
 @pytest.mark.parametrize("cell,fault", FAULTS,
                          ids=[f"{c}-{f.__name__}" for c, f in FAULTS])
 def test_fault_is_not_correct(cell, fault):
-    result = tiny.run(cell, fault=fault)
+    dep = _deployment(cell)
+    result = tiny.run(cell, fault=lambda instance: fault(instance, dep))
     assert not result["correct"], result["compared"]

@@ -1,12 +1,13 @@
-"""The one traffic generator: a mix file (`traffic/<name>.json`) of
-parameters, a world and a seed in; seeded columnar readings, the pool of
-encoded records, the publisher that keeps a backlog and the sampler of
-the commit edge out.
+"""What every mix shares: the mix file (`traffic/<name>.json`) of
+parameters, the size of its pool, the publisher that keeps a backlog and
+the sampler of the commit edge.
 
-Records are msgpack `DeviceEventBatch` envelopes keyed by device token on
-the tenant's decoded-events topic: the boundary where the event-sources
-service hands records to inbound processing. Every seed draws the same
-number of records of the same sizes; only devices, names and values move.
+The records themselves are made from the mix, a world and a seed by the
+deployment's module (`deployments/<name>.py`), or by the generator the mix
+names (`traffic/<generator>.py`): `make_traffic` and `encode_records`.
+They go on the tenant's decoded-events topic, keyed by device token: the
+boundary where the event-sources service hands records to inbound
+processing.
 """
 
 from __future__ import annotations
@@ -16,109 +17,28 @@ import json
 import os
 import threading
 import time
-from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+from benchmark import loader
+
 # how often the publisher looks at the backlog: a consumer cycle takes
 # seconds, so this keeps the backlog full at a small cost in the GIL
 CHECK_S = 0.005
 
 
 def load_mix(name: str, overrides: Optional[Dict] = None) -> Dict:
-    with open(os.path.join(BENCH_DIR, "traffic", f"{name}.json")) as fh:
+    with open(os.path.join(loader.TRAFFIC_DIR, f"{name}.json")) as fh:
         mix = json.load(fh)
     mix.update(overrides or {})
     return mix
 
 
-def events_per_record(mix: Dict) -> int:
-    return int(mix["readings_per_record"])
-
-
-@dataclass
-class Traffic:
-    """Columnar readings in publish order; record r holds readings
-    [r * E, (r + 1) * E) of device `record_dev[r]`."""
-
-    per_record: int
-    record_dev: np.ndarray   # [R] device number
-    dev: np.ndarray          # [N] device number
-    ts: np.ndarray           # [N] int64 ms, unique, increasing
-    mm: np.ndarray           # [N] measurement name number
-    value: np.ndarray        # [N] float32
-
-    @property
-    def n(self) -> int:
-        return int(self.dev.shape[0])
-
-    def prefix(self, n_records: int) -> "Traffic":
-        """The first `n_records` records."""
-        e = n_records * self.per_record
-        return Traffic(self.per_record, self.record_dev[:n_records],
-                       self.dev[:e], self.ts[:e], self.mm[:e],
-                       self.value[:e])
-
-
-def make_traffic(world, mix: Dict, seed: int, n_records: int,
-                 base_ms: int) -> Traffic:
-    """Seeded records of one device each, uniform over the fleet; every
-    reading has its own millisecond so "last" is unambiguous everywhere."""
-    rng = np.random.default_rng([seed, 2])
-    per = events_per_record(mix)
-    n = n_records * per
-    record_dev = rng.integers(0, world.n, n_records)
-    lo, hi = mix["value_range"]
-    return Traffic(
-        per, record_dev, np.repeat(record_dev, per),
-        base_ms + np.arange(n, dtype=np.int64),
-        rng.integers(0, len(world.cfg["measurement_names"]), n),
-        rng.uniform(lo, hi, n).astype(np.float32))
-
-
-def encode_records(world, traffic: Traffic, mix: Dict,
-                   source: str = "bench") -> List[Tuple[bytes, bytes]]:
-    """Every record as (key, msgpack DeviceEventBatch envelope), padded
-    with a metadata payload to `record_bytes` where the mix asks for it."""
-    import msgpack
-
-    names = world.cfg["measurement_names"]
-    target = int(mix.get("record_bytes", 0))
-    mms, values = traffic.mm.tolist(), traffic.value.tolist()
-    tss = traffic.ts.tolist()
-    tokens = world.tokens
-    per = traffic.per_record
-    pad = ""
-    records = []
-    for r, d in enumerate(traffic.record_dev.tolist()):
-        token = tokens[d]
-        request = {"device_token": token, "measurements": [],
-                   "locations": [], "alerts": []}
-        for i in range(r * per, (r + 1) * per):
-            event = {"event_type": 0, "name": names[mms[i]],
-                     "value": values[i], "event_date": tss[i]}
-            if target:
-                event["metadata"] = {"sensor_key": token, "payload": pad}
-            request["measurements"].append(event)
-        envelope = {"sourceId": source, "deviceToken": token,
-                    "kind": "DeviceEventBatch", "request": request,
-                    "metadata": {}}
-        value = msgpack.packb(envelope, use_bin_type=True)
-        if target and r == 0:
-            # size the padding once, from the first record
-            pad = "x" * max(0, target - len(value))
-            request["measurements"][0]["metadata"]["payload"] = pad
-            value = msgpack.packb(envelope, use_bin_type=True)
-        records.append((token.encode(), value))
-    return records
-
-
-def pool_records(mix: Dict) -> int:
-    """Records the pool holds: warm-up plus what the window can take (at
-    least four times the rate served today, over the longest window)."""
-    per = events_per_record(mix)
+def pool_records(mix: Dict, per: int) -> int:
+    """Records the pool holds, at `per` events to a record: warm-up plus
+    what the window can take (at least four times the rate served today,
+    over the longest window)."""
     return (int(mix["warmup_records"])
             + -(-int(mix["pool_events"]) // per))
 

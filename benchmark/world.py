@@ -1,5 +1,6 @@
 """A deployment's world: the instance as ``serve`` builds it, provisioned
-through the control-plane API from a configuration file, and kept as a
+through the control-plane API by the deployment's module
+(``deployments/<name>.py``) from a configuration file, and kept as a
 data-directory snapshot so that later runs boot from it.
 
 The world is a function of the configuration alone; a run's seed varies
@@ -23,17 +24,17 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
-CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
-# bump when the provisioning below changes what a snapshot holds
+from benchmark import loader
+
+CACHE_DIR = os.path.join(loader.BENCH_DIR, ".cache")
+# bump when provisioning changes what a snapshot holds
 SNAPSHOT_VERSION = 1
-THRESHOLD_TYPE = "bench.threshold"
 
 
 def load_config(name: str, overrides: Optional[Dict] = None) -> Dict:
     """The configuration file `configs/<name>.json`, with `overrides`
     (tests shrink the scale) merged at the top level."""
-    with open(os.path.join(BENCH_DIR, "configs", f"{name}.json")) as fh:
+    with open(os.path.join(loader.CONFIG_DIR, f"{name}.json")) as fh:
         cfg = json.load(fh)
     for key, value in (overrides or {}).items():
         if isinstance(value, dict) and isinstance(cfg.get(key), dict):
@@ -48,41 +49,30 @@ def config_digest(cfg: Dict) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def n_devices(cfg: Dict) -> int:
-    return int(cfg["areas"]) * int(cfg["devices_per_area"])
-
-
-def rule_dicts(cfg: Dict) -> List[Dict]:
-    """The fused rules as `serve` config entries (the `rules` list that
-    `_apply_rule_config` installs at every boot)."""
-    return [{"type": "threshold", "token": f"thr-{i}",
-             "measurement_name": mm, "operator": op, "threshold": value,
-             "alert_type": THRESHOLD_TYPE, "alert_level": level}
-            for i, (mm, op, value, level) in enumerate(cfg["threshold_rules"])]
-
-
 # ---------------------------------------------------------------- boot --
 
-def serve_config(cfg: Dict, data_dir: str):
-    """The `serve` configuration of this deployment over `data_dir`."""
+def serve_config(cfg: Dict, data_dir: str, rules: List[Dict]):
+    """The `serve` configuration of this deployment over `data_dir`, with
+    the deployment's fused `rules` (installed at every boot)."""
     from sitewhere_tpu.__main__ import _build_config
 
     serve = _build_config(None)
     serve.set("persist.data_dir", data_dir)
     for key, value in cfg["serve_config"].items():
         serve.set(key, value)
-    serve.set("rules", rule_dicts(cfg))
+    serve.set("rules", rules)
     return serve
 
 
-def boot(cfg: Dict, data_dir: str, geofence_impl: Optional[str] = None):
+def boot(cfg: Dict, data_dir: str, rules: List[Dict],
+         geofence_impl: Optional[str] = None):
     """Instance + REST gateway on port 0, as `serve` boots them:
     `_build_instance`, start, the config's rules, `RestServer`.
     `geofence_impl` is for CPU tests (the kernel in interpret mode)."""
     from sitewhere_tpu.__main__ import _apply_rule_config, _build_instance
     from sitewhere_tpu.web.server import RestServer
 
-    serve = serve_config(cfg, data_dir)
+    serve = serve_config(cfg, data_dir, rules)
     instance = _build_instance(serve)
     engine = instance.pipeline_engine
     if geofence_impl is not None and engine.geofence_impl != geofence_impl:
@@ -97,32 +87,14 @@ def boot(cfg: Dict, data_dir: str, geofence_impl: Optional[str] = None):
     return instance, rest
 
 
-def provision(instance, cfg: Dict) -> None:
-    """Register the world through the control-plane API: the device type,
-    the areas and the devices with their assignments."""
-    from sitewhere_tpu.model import Area, Device, DeviceAssignment, DeviceType
-
-    reg = instance.get_tenant_engine(cfg["tenant"]).registry
-    dtype = reg.create_device_type(DeviceType(token="bench-sensor",
-                                              name="bench sensor"))
-    areas = [reg.create_area(Area(token=f"area-{a}", name=f"area {a}"))
-             for a in range(int(cfg["areas"]))]
-    per_area = int(cfg["devices_per_area"])
-    for i in range(n_devices(cfg)):
-        device = reg.create_device(Device(token=f"dev-{i}",
-                                          device_type_id=dtype.id))
-        reg.create_device_assignment(DeviceAssignment(
-            token=f"as-{i}", device_id=device.id,
-            area_id=areas[i // per_area].id))
-
-
 def snapshot_dir(cfg: Dict) -> str:
     return os.path.join(CACHE_DIR, cfg["name"], config_digest(cfg))
 
 
-def ensure_snapshot(cfg: Dict, log) -> float:
-    """Provision the world into a snapshot unless this checkout has one;
-    returns the seconds spent provisioning (0 when it was there)."""
+def ensure_snapshot(cfg: Dict, dep, log) -> float:
+    """Provision the world into a snapshot, by the deployment's module
+    `dep`, unless this checkout has one; returns the seconds spent
+    provisioning (0 when it was there)."""
     final = snapshot_dir(cfg)
     if os.path.isdir(final):
         return 0.0
@@ -130,16 +102,16 @@ def ensure_snapshot(cfg: Dict, log) -> float:
     staging = final + ".partial"
     shutil.rmtree(staging, ignore_errors=True)
     os.makedirs(staging)
-    instance, rest = boot(cfg, staging)
+    instance, rest = boot(cfg, staging, dep.rule_dicts(cfg))
     try:
-        provision(instance, cfg)
+        dep.provision(instance, cfg)
     finally:
         rest.stop()
         instance.stop()
     os.replace(staging, final)
     seconds = time.perf_counter() - t0
     log(f"world: provisioned {cfg['name']} into a new snapshot "
-        f"({n_devices(cfg)} devices) provision_s={seconds:.3f}")
+        f"({dep.n_devices(cfg)} devices) provision_s={seconds:.3f}")
     return seconds
 
 
@@ -163,10 +135,10 @@ class World:
         return int(self.tokens.shape[0])
 
 
-def attach(instance, cfg: Dict) -> World:
-    """The lookups the harness needs from a booted world."""
+def attach_devices(instance, cfg: Dict, tokens: List[str]) -> World:
+    """The lookups the harness needs from a booted world whose devices
+    are `tokens`."""
     engine = instance.pipeline_engine
-    tokens = [f"dev-{i}" for i in range(n_devices(cfg))]
     lookup = engine.registry.devices.lookup
     device_idx = np.array([lookup(t) for t in tokens], np.int64)
     if (device_idx <= 0).any():
