@@ -74,6 +74,9 @@ def _build_instance(cfg, mesh=None):
         batch_size=batch_size,
         measurement_slots=int(cfg.get("pipeline.measurement_slots")),
         max_tenants=int(cfg.get("pipeline.max_tenants")),
+        max_threshold_rules=int(cfg.get("pipeline.max_threshold_rules")),
+        max_geofence_rules=int(cfg.get("pipeline.max_geofence_rules")),
+        alert_lane_capacity=int(cfg.get("pipeline.alert_lane_capacity")),
         bus_partitions=int(cfg.get("bus.partitions")),
         default_tenant=cfg.get("instance.default_tenant"),
         admin_username=cfg.get("instance.admin_username"),

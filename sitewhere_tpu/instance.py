@@ -70,7 +70,10 @@ class SiteWhereInstance(LifecycleComponent):
                  serving_latency_budget_ms: float = 0.0,
                  serving_cache_mb: float = 64.0,
                  serving_mesh_row_threshold: Optional[int] = None,
-                 refit_interval_s: Optional[float] = None):
+                 refit_interval_s: Optional[float] = None,
+                 max_threshold_rules: int = 256,
+                 max_geofence_rules: int = 256,
+                 alert_lane_capacity: Optional[int] = None):
         super().__init__(f"instance:{instance_id}")
         self.instance_id = instance_id
         self.data_dir = data_dir
@@ -138,14 +141,20 @@ class SiteWhereInstance(LifecycleComponent):
                     measurement_slots=measurement_slots,
                     max_tenants=max_tenants,
                     device_routing=device_routing,
-                    h2d_buffer_depth=h2d_buffer_depth)
+                    h2d_buffer_depth=h2d_buffer_depth,
+                    max_threshold_rules=max_threshold_rules,
+                    max_geofence_rules=max_geofence_rules,
+                    alert_lane_capacity=alert_lane_capacity)
             else:
                 from sitewhere_tpu.pipeline.engine import PipelineEngine
                 self.pipeline_engine = PipelineEngine(
                     self.registry_tensors, batch_size=batch_size,
                     measurement_slots=measurement_slots,
                     max_tenants=max_tenants,
-                    h2d_buffer_depth=h2d_buffer_depth)
+                    h2d_buffer_depth=h2d_buffer_depth,
+                    max_threshold_rules=max_threshold_rules,
+                    max_geofence_rules=max_geofence_rules,
+                    alert_lane_capacity=alert_lane_capacity)
         # latency tier (pipeline.mode="latency"): one shared adaptive
         # batcher coalesces every tenant's hot events and flushes on fill
         # or linger (pipeline/feed.py) — inbound consumers offer to it
@@ -884,6 +893,10 @@ class SiteWhereInstance(LifecycleComponent):
         if engine is not None:
             extra["pipeline.batches_processed"] = engine.batches_processed
             extra["pipeline.alerts_dropped"] = engine.alerts_dropped
+            extra["pipeline.alert_lane.capacity"] = \
+                engine.alert_lane_capacity
+            extra["pipeline.alert_lane.fill_max"] = \
+                engine.alert_lane_fill_max
             health = getattr(engine, "health", None)
             if health is not None:
                 # 0=healthy 1=degraded 2=draining 3=failed

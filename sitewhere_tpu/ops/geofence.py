@@ -42,6 +42,9 @@ class ZoneTable:
 class GeofenceCondition:
     INSIDE = 0   # fire when the point IS in the zone
     OUTSIDE = 1  # fire when the point is NOT in the zone
+    ENTER = 2    # fire when the point is in the zone and the device's
+                 # previous location was not (previous_locations)
+    BY_NAME = {"inside": INSIDE, "outside": OUTSIDE, "enter": ENTER}
 
 
 @struct.dataclass
@@ -102,6 +105,40 @@ def points_in_zones(lat: jnp.ndarray, lon: jnp.ndarray,
     return parity
 
 
+def previous_locations(dev: jnp.ndarray, ts: jnp.ndarray,
+                       is_loc: jnp.ndarray, lat: jnp.ndarray,
+                       lon: jnp.ndarray, last_location: jnp.ndarray,
+                       last_location_ts: jnp.ndarray):
+    """Each row's previous location of its device, for the `enter`
+    condition: (lat [B], lon [B], has [B]).
+
+    For a location row e of device d, the previous location is that of
+    d's latest location row in the same batch that orders before e by
+    (date, batch position); failing that, the state's last location of d
+    where `last_location_ts` is set; failing both, there is none (`has`
+    False). Only `is_loc` rows (valid location events) count as
+    predecessors. No history is kept beyond the state's one location, so
+    a report dated before the state's last location still compares with
+    that state.
+    """
+    B = dev.shape[0]
+    D = last_location.shape[0]
+    pos = jnp.arange(B, dtype=jnp.int32)
+    key = jnp.where(is_loc, dev, D)
+    # primary device, then date, then batch position
+    order = jnp.lexsort((pos, ts, key))
+    skey = key[order]
+    same = jnp.concatenate([jnp.zeros((1,), bool), skey[1:] == skey[:-1]])
+    before = jnp.concatenate([order[:1], order[:-1]])
+    in_batch = jnp.zeros((B,), bool).at[order].set(same) & is_loc
+    prev_row = jnp.zeros((B,), order.dtype).at[order].set(before)
+    held = last_location_ts[dev] != jnp.iinfo(jnp.int32).min
+    state_loc = last_location[dev]
+    plat = jnp.where(in_batch, lat[prev_row], state_loc[:, 0])
+    plon = jnp.where(in_batch, lon[prev_row], state_loc[:, 1])
+    return plat, plon, in_batch | held
+
+
 def resolve_geofence_impl(impl: str, platform: str) -> str:
     """Resolve an `auto` containment implementation choice for a platform.
 
@@ -133,8 +170,15 @@ def _containment(lat: jnp.ndarray, lon: jnp.ndarray, vertices: jnp.ndarray,
 
 def eval_geofence_rules(batch: EventBatch, zones: ZoneTable,
                         rules: GeofenceRuleTable,
-                        impl: str = "xla") -> Dict[str, jnp.ndarray]:
+                        impl: str = "xla",
+                        prev=None) -> Dict[str, jnp.ndarray]:
     """Evaluate geofence rules against the location events of a batch.
+
+    `prev` is previous_locations' (lat, lon, has) for the `enter`
+    condition, or None where no `enter` rule is installed: the previous
+    points are then neither tested nor traced. With `prev` the current
+    and previous points are tested in one containment call over 2B
+    points, with the same kernel and arithmetic.
 
     Returns per-event outputs (shape [B]):
       fired:       bool, any geofence rule fired
@@ -147,7 +191,15 @@ def eval_geofence_rules(batch: EventBatch, zones: ZoneTable,
     is_location = batch.event_type == DeviceEventType.LOCATION
     event_ok = batch.valid & is_location                        # [B]
 
-    inside = _containment(batch.lat, batch.lon, zones.vertices, impl)  # [B,Z]
+    if prev is None:
+        inside = _containment(batch.lat, batch.lon, zones.vertices,
+                              impl)                              # [B,Z]
+    else:
+        B = batch.lat.shape[0]
+        both = _containment(jnp.concatenate([batch.lat, prev[0]]),
+                            jnp.concatenate([batch.lon, prev[1]]),
+                            zones.vertices, impl)                # [2B,Z]
+        inside, prev_inside = both[:B], both[B:]
     zone_ok = (zones.active[None, :]
                & ((zones.tenant_idx[None, :] == 0)
                   | (zones.tenant_idx[None, :] == batch.tenant_idx[:, None])))
@@ -158,6 +210,13 @@ def eval_geofence_rules(batch: EventBatch, zones: ZoneTable,
     rule_zone_ok = zone_ok[:, rules.zone_row]
     cond_met = jnp.where(rules.condition[None, :] == GeofenceCondition.INSIDE,
                          rule_inside, rule_zone_ok & ~rule_inside)
+    if prev is not None:
+        # the device came from outside the zone, or from nowhere
+        was_inside = prev[2][:, None] & (prev_inside & zone_ok)[
+            :, rules.zone_row]
+        cond_met = jnp.where(
+            rules.condition[None, :] == GeofenceCondition.ENTER,
+            rule_inside & ~was_inside, cond_met)
     fired_matrix = (rules.active[None, :] & event_ok[:, None] & cond_met)
 
     fired_count = jnp.sum(fired_matrix, axis=1, dtype=jnp.int32)

@@ -522,7 +522,7 @@ class ShardedPipelineEngine(PipelineEngine):
             # alert lane
             command_lanes=dev)
         (programs_enabled, node_limit, models_enabled,
-         actuation_enabled) = self._step_static_config()
+         actuation_enabled, geofence_enter) = self._step_static_config()
 
         def sq(a):
             # shard_map hands blocks with the mapped axis kept (size 1); the
@@ -558,7 +558,8 @@ class ShardedPipelineEngine(PipelineEngine):
                 program_node_limit=node_limit,
                 models_enabled=models_enabled,
                 actuation_enabled=actuation_enabled,
-                command_lane_capacity=self.command_lane_capacity)
+                command_lane_capacity=self.command_lane_capacity,
+                geofence_enter=geofence_enter)
             lanes = out.alert_lanes
             if route_dropped is not None:
                 from sitewhere_tpu.ops.route import ROUTE_DROPPED_SLOT
@@ -635,7 +636,8 @@ class ShardedPipelineEngine(PipelineEngine):
         else:
             self._sharded_step_device = None
         self._sharded_built_config = (programs_enabled, node_limit,
-                                      models_enabled, actuation_enabled)
+                                      models_enabled, actuation_enabled,
+                                      geofence_enter)
 
     # -- params ---------------------------------------------------------------
 
@@ -1067,6 +1069,7 @@ class ShardedPipelineEngine(PipelineEngine):
                     for s in range(lanes.shape[0])]
             self._account_lane_overflow(
                 sum(d.dropped_alerts for d in decs))
+            self._account_lane_fill(decs, rec)
             self._account_route_dropped(
                 sum(d.route_dropped for d in decs))
             if not any(d.n for d in decs):

@@ -157,7 +157,10 @@ class GeofenceRule:
 
     token: str
     zone_token: str = ""
-    condition: str = "outside"       # fire when point is inside|outside
+    # fire when the point is inside|outside the zone, or `enter`: inside
+    # it, and the device's previous location was not
+    # (ops/geofence.py previous_locations)
+    condition: str = "outside"
     alert_type: str = "zone.violation"
     alert_level: AlertLevel = AlertLevel.ERROR
     alert_message: str = ""
@@ -224,9 +227,9 @@ def rule_from_dict(data: Dict):
         return kind, rule
     if kind == "geofence":
         rule = GeofenceRule(**fields_for(GeofenceRule))
-        if rule.condition not in ("inside", "outside"):
+        if rule.condition not in GeofenceCondition.BY_NAME:
             raise SiteWhereError(
-                f"geofence condition must be inside|outside, got "
+                f"geofence condition must be inside|outside|enter, got "
                 f"{rule.condition!r}", ErrorCode.GENERIC)
         if not rule.zone_token:
             raise SiteWhereError("geofence rule requires zone_token",
@@ -329,6 +332,8 @@ class PipelineEngine(LifecycleComponent):
 
         self._threshold_rules: List[ThresholdRule] = []
         self._geofence_rules: List[GeofenceRule] = []
+        # (rules version, GeofenceCondition code per geofence rule id)
+        self._geo_codes: Tuple[int, np.ndarray] = (-1, np.zeros(0, int))
         # rule programs: token -> {"slot", "epoch", "spec"} with STABLE
         # slot assignment (lowest free slot on install) — per-(device,
         # program) temporal state is keyed by slot, and the epoch
@@ -408,6 +413,14 @@ class PipelineEngine(LifecycleComponent):
         # bounded materialization (max_alerts) AND alert-lane overflow
         # (> capacity fired rows in one step) both count here
         self.alerts_dropped = 0
+        # the most fired rows one step's alert lane (one shard's, on a
+        # mesh) was handed, against alert_lane_capacity
+        self.alert_lane_fill_max = 0
+        # geofence fires by condition (see _account_lane_fill)
+        self._geo_fire_counters = (
+            self._metrics.counter("geofence.fires_inside"),
+            self._metrics.counter("geofence.fires_outside"),
+            self._metrics.counter("geofence.fires_enter"))
         # D2H materialization accounting: how many fetches / bytes the
         # alert path ships per step — the latency tier's fetch budget
         # (perf_gate latency_fetch_budget) reads the per-offer deltas
@@ -456,14 +469,22 @@ class PipelineEngine(LifecycleComponent):
 
     def _step_static_config(self):
         """Trace-time statics of the stateful stages: (programs enabled,
-        node trim, models enabled). A change — programs or models going
+        node trim, models enabled, actuation enabled, geofence `enter`
+        installed). A change — programs or models going
         empty<->non-empty, or a program using more node slots than any
         before — rebuilds the jit (rare; a normal table edit reuses the
         compiled program like any other params refresh)."""
         return (self._programs_enabled,
                 getattr(self, "_program_nodes_in_use", 0),
                 self._models_enabled,
-                self._actuation_enabled)
+                self._actuation_enabled,
+                self._geofence_enter_enabled())
+
+    def _geofence_enter_enabled(self) -> bool:
+        """Whether an `enter` geofence rule is installed: the step then
+        traces the previous-location containment, and only then."""
+        codes = self._geofence_condition_codes()
+        return bool((codes == GeofenceCondition.ENTER).any())
 
     def _build_step_blob(self) -> None:
         """(Re)build the jitted fused step. Called at construction and on
@@ -472,7 +493,7 @@ class PipelineEngine(LifecycleComponent):
         pays nothing — one recompile per transition, like any other
         static-shape change."""
         (programs_enabled, node_limit, models_enabled,
-         actuation_enabled) = self._step_static_config()
+         actuation_enabled, geofence_enter) = self._step_static_config()
 
         def step_blob(params, state, rule_state, model_state,
                       actuation_state, blob):
@@ -485,11 +506,13 @@ class PipelineEngine(LifecycleComponent):
                                  models_enabled=models_enabled,
                                  actuation_enabled=actuation_enabled,
                                  command_lane_capacity=(
-                                     self.command_lane_capacity))
+                                     self.command_lane_capacity),
+                                 geofence_enter=geofence_enter)
 
         self._step_blob = jax.jit(step_blob, donate_argnums=(1, 2, 3, 4))
         self._step_built_config = (programs_enabled, node_limit,
-                                   models_enabled, actuation_enabled)
+                                   models_enabled, actuation_enabled,
+                                   geofence_enter)
 
     def _ensure_step_current(self) -> None:
         if self._step_built_config != self._step_static_config():
@@ -723,9 +746,8 @@ class PipelineEngine(LifecycleComponent):
             zidx = self.registry.zones_interner.lookup(rule.zone_token)
             table.active[i] = rule.active and zidx > 0
             table.zone_row[i] = max(0, zidx - 1)
-            table.condition[i] = (GeofenceCondition.INSIDE
-                                  if rule.condition == "inside"
-                                  else GeofenceCondition.OUTSIDE)
+            table.condition[i] = GeofenceCondition.BY_NAME.get(
+                rule.condition, GeofenceCondition.OUTSIDE)
             table.alert_level[i] = int(rule.alert_level)
             table.alert_type_idx[i] = self.packer.alert_types.intern(rule.alert_type)
         return table
@@ -1646,6 +1668,7 @@ class PipelineEngine(LifecycleComponent):
             self.d2h_bytes += lanes.nbytes + cmd_lanes.nbytes
             dec = decode_alert_lanes(lanes)
             self._account_lane_overflow(dec.dropped_alerts)
+            self._account_lane_fill([dec], rec)
             dec = self._bound_alert_rows(dec, max_alerts)
             if dec.n == 0:
                 return pending
@@ -1774,6 +1797,43 @@ class PipelineEngine(LifecycleComponent):
             "alert-lane overflow: %d alerts beyond the %d-row lane "
             "capacity dropped on device (alerts_dropped=%d total)",
             dropped, self.alert_lane_capacity, self.alerts_dropped)
+
+    def _geofence_condition_codes(self) -> np.ndarray:
+        """GeofenceCondition code of each installed geofence rule, by
+        rule id; rebuilt when the rules change."""
+        version, codes = self._geo_codes
+        if version != self._rules_version:
+            with self._lock:
+                version = self._rules_version
+                codes = np.array(
+                    [GeofenceCondition.BY_NAME.get(
+                        r.condition, GeofenceCondition.OUTSIDE)
+                     for r in self._geofence_rules], np.int64)
+            self._geo_codes = (version, codes)
+        return codes
+
+    def _account_lane_fill(self, decs, rec) -> None:
+        """One step's decoded alert lanes (one per shard): the fill of
+        the fullest lane against alert_lane_capacity, and the geofence
+        fires by the condition of each fired row's first geofence rule
+        (the rule its alert names; rows past capacity are not seen).
+        Both ride the step's flight record (`lane`, `geofence_fires`);
+        the fires also count on `geofence.fires_<condition>`."""
+        fill = max((d.fired_rows for d in decs), default=0)
+        self.alert_lane_fill_max = max(self.alert_lane_fill_max, fill)
+        codes = self._geofence_condition_codes()
+        fires = np.zeros(3, np.int64)
+        for d in decs:
+            rule = d.geo_rule[d.geo_fired]
+            rule = rule[(rule >= 0) & (rule < codes.shape[0])]
+            if rule.size:
+                fires += np.bincount(codes[rule], minlength=3)[:3]
+        for counter, n in zip(self._geo_fire_counters, fires.tolist()):
+            if n:
+                counter.inc(n)
+        if rec is not None:
+            rec.lane = (fill, self.alert_lane_capacity)
+            rec.geo_fires = tuple(fires.tolist())
 
     def _bound_alert_rows(self, dec, max_alerts: Optional[int]):
         """Apply a caller's max_alerts bound to decoded lanes (row count,

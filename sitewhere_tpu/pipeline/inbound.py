@@ -305,7 +305,7 @@ class InboundProcessingService(LifecycleComponent):
                     cycle.close("materialize")
                 cycle.open("alert_persist")
                 try:
-                    self._persist_alerts(alerts)
+                    cycle.count_alerts(self._persist_alerts(alerts))
                 finally:
                     cycle.close("alert_persist")
         if persist and self.batcher is None:
@@ -314,7 +314,8 @@ class InboundProcessingService(LifecycleComponent):
             # persist with it — nothing waits on traffic that may never come
             cycle.open("alert_persist")
             try:
-                self._persist_alerts(self.engine.drain_parked())
+                cycle.count_alerts(
+                    self._persist_alerts(self.engine.drain_parked()))
             finally:
                 cycle.close("alert_persist")
 
@@ -337,7 +338,10 @@ class InboundProcessingService(LifecycleComponent):
             cycle.caused(getattr(self.engine, "_flight_last", None))
             yield pair
 
-    def _persist_alerts(self, alerts) -> None:
+    def _persist_alerts(self, alerts) -> int:
+        """Persist rule alerts on their devices' active assignments;
+        returns how many were persisted."""
+        n = 0
         for alert in alerts:
             device = self.registry.get_device_by_token(alert.device_id)
             if device is None:
@@ -346,3 +350,5 @@ class InboundProcessingService(LifecycleComponent):
             if assignment is None:
                 continue
             self.events.add_alerts(assignment.token, alert)
+            n += 1
+        return n

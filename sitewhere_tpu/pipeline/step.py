@@ -31,7 +31,7 @@ from sitewhere_tpu.ops.compact import (
     DEFAULT_ALERT_LANE_CAPACITY, compact_alert_lanes,
 )
 from sitewhere_tpu.ops.geofence import (
-    GeofenceRuleTable, ZoneTable, eval_geofence_rules,
+    GeofenceRuleTable, ZoneTable, eval_geofence_rules, previous_locations,
 )
 from sitewhere_tpu.ops.pack import EventBatch
 from sitewhere_tpu.ops.segments import (
@@ -129,7 +129,8 @@ def process_batch(params: PipelineParams, state: DeviceStateTensors,
                   program_node_limit: int = 0,
                   models_enabled: bool = True,
                   actuation_enabled: bool = True,
-                  command_lane_capacity: int = DEFAULT_COMMAND_LANE_CAPACITY
+                  command_lane_capacity: int = DEFAULT_COMMAND_LANE_CAPACITY,
+                  geofence_enter: bool = False
                   ) -> Tuple[DeviceStateTensors, RuleStateTensors,
                              ModelStateTensors, ActuationStateTensors,
                              ProcessOutputs]:
@@ -153,6 +154,10 @@ def process_batch(params: PipelineParams, state: DeviceStateTensors,
     when no policies are installed — the command lane is then a zero
     placeholder so the materialize fetch shape never changes.
     `command_lane_capacity` is the K of the compacted command lane.
+    `geofence_enter` (trace-time static) adds the `enter` condition's
+    previous locations (ops/geofence.py previous_locations) and their
+    containment; without an `enter` rule installed the step is traced
+    without them.
     """
     D = state.num_devices
     M = state.num_measurement_slots
@@ -176,8 +181,15 @@ def process_batch(params: PipelineParams, state: DeviceStateTensors,
     # ---- stage 2: rule evaluation (replaces rule-processing service) -------
     with jax.named_scope("step_rules"):
         thr = eval_threshold_rules(batch, params.threshold, device_type)
+        prev = None
+        if geofence_enter:
+            prev = previous_locations(
+                batch.device_idx, batch.ts,
+                valid & (batch.event_type == DeviceEventType.LOCATION),
+                batch.lat, batch.lon, state.last_location,
+                state.last_location_ts)
         geo = eval_geofence_rules(batch, params.zones, params.geofence,
-                                  impl=geofence_impl)
+                                  impl=geofence_impl, prev=prev)
 
     # ---- stage 3: device-state fold (replaces device-state service) --------
     with jax.named_scope("step_state_fold"):

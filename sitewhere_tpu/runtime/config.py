@@ -194,6 +194,10 @@ DEFAULTS: Dict = {
         "max_zones": 256,
         "max_zone_vertices": 32,
         "max_threshold_rules": 256,
+        "max_geofence_rules": 256,
+        # fired rows per step the device-compacted alert lanes hold
+        # (ops/compact.py); rows past it count on alerts_dropped
+        "alert_lane_capacity": 128,
         "max_measurement_names": 1024,
         "max_tenants": 16,
         "measurement_slots": 8,

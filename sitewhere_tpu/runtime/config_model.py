@@ -125,6 +125,10 @@ def pipeline_model() -> ElementModel:
             _attr("max_tenants", _I, default=16),
             _attr("max_threshold_rules", _I, default=256),
             _attr("max_geofence_rules", _I, default=256),
+            _attr("alert_lane_capacity", _I, default=128,
+                  description="fired rows per step the device-compacted "
+                              "alert lanes carry (ops/compact.py); rows "
+                              "past it count on alerts_dropped"),
             _attr("presence_missing_interval_ms", _I,
                   default=8 * 60 * 60 * 1000,
                   description="DevicePresenceManager missing interval"),
@@ -260,7 +264,7 @@ def rule_processing_model() -> ElementModel:
                           choices=[">", ">=", "<", "<=", "==", "!="]),
                     _attr("threshold", _D),
                     _attr("zone_token", AttributeType.ZONE_REF),
-                    _attr("condition", choices=["inside", "outside"]),
+                    _attr("condition", choices=["inside", "outside", "enter"]),
                     _attr("alert_level", _I),
                     _attr("alert_type"),
                     _attr("script", AttributeType.SCRIPT)])

@@ -154,6 +154,7 @@ class StepRecord:
 
     __slots__ = ("seq", "gen", "engine", "events", "tenant_mix",
                  "begin", "end", "created", "age", "ring", "commands",
+                 "lane", "geo_fires",
                  "stages", "_index", "_spans", "_open", "_unset")
 
     def __init__(self, stages: Tuple[str, ...] = STAGES,
@@ -182,6 +183,11 @@ class StepRecord:
         # command fires resolved from this step's command lane (actuate
         # stage); drives the detection_to_actuation age edge
         self.commands = 0
+        # alert lane at materialize: (fired rows of the fullest lane,
+        # capacity); None until the step's lanes are decoded
+        self.lane: Optional[Tuple[int, int]] = None
+        # geofence fires by condition (inside, outside, enter)
+        self.geo_fires: Optional[Tuple[int, int, int]] = None
 
     # -- hot path -----------------------------------------------------
     def reset(self, seq: int, gen: int, engine: str) -> None:
@@ -196,6 +202,8 @@ class StepRecord:
         self.age = None
         self.ring = None
         self.commands = 0
+        self.lane = None
+        self.geo_fires = None
 
     def mark(self, stage: str, t0: float, t1: float) -> None:
         """Record a completed segment from explicit timestamps."""
@@ -242,6 +250,8 @@ class StepRecord:
         copy.created = self.created
         copy.age = self.age
         copy.ring = self.ring
+        copy.lane = self.lane
+        copy.geo_fires = self.geo_fires
         return copy
 
     def span_bounds(self) -> Optional[Tuple[float, float]]:
@@ -293,6 +303,12 @@ class StepRecord:
         if self.ring is not None:
             out["ring"] = {"occupancy": self.ring[0],
                            "depth": self.ring[1]}
+        if self.lane is not None:
+            out["lane"] = {"fired_rows": self.lane[0],
+                           "capacity": self.lane[1]}
+        if self.geo_fires is not None:
+            out["geofence_fires"] = dict(zip(
+                ("inside", "outside", "enter"), self.geo_fires))
         age = self.age
         if age is not None and hasattr(age, "export"):
             exported = age.export()
@@ -311,8 +327,8 @@ class CycleRecord(StepRecord):
     handed the record explicitly; nothing rides a thread-local.
     ``traced`` is read once per cycle, when the cycle is claimed."""
 
-    __slots__ = ("records", "cpu_s", "error", "steps", "traced", "acc", "n",
-                 "_t0", "_blank")
+    __slots__ = ("records", "alerts", "cpu_s", "error", "steps", "traced",
+                 "acc", "n", "_t0", "_blank")
 
     def __init__(self, stages: Tuple[str, ...] = CYCLE_STAGES) -> None:
         super().__init__(stages, CYCLE_SPANS)
@@ -322,6 +338,7 @@ class CycleRecord(StepRecord):
         self._blank = ((0.0,) * len(stages), (0,) * len(stages),
                        (None,) * len(stages))
         self.records = 0
+        self.alerts = 0       # rule alerts the handler persisted
         self.cpu_s = 0.0      # consumer thread CPU over handler + commit
         self.error: Optional[str] = None
         self.steps: List[int] = []   # seq of each step record it caused
@@ -332,6 +349,7 @@ class CycleRecord(StepRecord):
         super().reset(seq, gen, engine)
         self.acc[:], self.n[:], self._open[:] = self._blank
         self.records = 0
+        self.alerts = 0
         self.cpu_s = 0.0
         self.error = None
         self.steps = []
@@ -363,6 +381,10 @@ class CycleRecord(StepRecord):
         if step is not None:
             self.steps.append(step.seq)
 
+    def count_alerts(self, n: int) -> None:
+        """Add `n` rule alerts the handler persisted."""
+        self.alerts += n
+
     # -- cold path ----------------------------------------------------
     def _duration(self, i: int) -> float:
         if self.n[i]:
@@ -374,6 +396,7 @@ class CycleRecord(StepRecord):
         copy.acc = list(self.acc)
         copy.n = list(self.n)
         copy.records = self.records
+        copy.alerts = self.alerts
         copy.cpu_s = self.cpu_s
         copy.error = self.error
         copy.steps = list(self.steps)
@@ -395,6 +418,7 @@ class CycleRecord(StepRecord):
             "consumer": self.engine,
             "records": self.records,
             "events": self.events,
+            "alerts": self.alerts,
             "stages": stages,
             "span_ms": round((bounds[1] - bounds[0]) * 1e3, 6)
             if bounds else 0.0,
@@ -416,6 +440,9 @@ class _NoCycle:
         pass
 
     def close(self, stage: str) -> None:
+        pass
+
+    def count_alerts(self, n: int) -> None:
         pass
 
     def caused(self, step) -> None:

@@ -228,6 +228,40 @@ class TestDifferentialSingleChip:
         assert (engine._metrics.counter("alerts.dropped").value
                 == dec.dropped_alerts)
 
+    def test_raised_capacity_carries_a_fire_rate_above_the_default(self):
+        """A deployment that fires on a large share of its rows (a
+        toll notification per segment crossing) raises the capacity:
+        every fired row then rides the lanes, equal to the mask scan,
+        and the lane's fill is recorded against the capacity."""
+        from sitewhere_tpu.ops.compact import DEFAULT_ALERT_LANE_CAPACITY
+
+        _, tensors = _world()
+        engine = PipelineEngine(tensors, batch_size=512,
+                                measurement_slots=8, max_tenants=4,
+                                max_threshold_rules=16,
+                                max_geofence_rules=16,
+                                alert_lane_capacity=512,
+                                name=_unique_name())
+        engine.start()
+        _add_rules(engine)
+        events, tokens = _mixed_events(400, fire_every=1)
+        batch, out = self._submit(engine, events, tokens)
+        dec = decode_alert_lanes(np.asarray(out.alert_lanes))
+        assert dec.fired_rows > DEFAULT_ALERT_LANE_CAPACITY
+        ref = materialize_alerts_maskscan(engine, batch, out)
+        got = engine.materialize_alerts(batch, out)
+        assert [_key(a) for a in got] == [_key(a) for a in ref]
+        assert engine.alerts_dropped == 0
+        assert engine.alert_lane_fill_max == dec.fired_rows
+        rec = engine.flight.export(last_n=1)["records"][-1]
+        assert rec["lane"] == {"fired_rows": dec.fired_rows,
+                               "capacity": 512}
+        geo = sum(a.type == "zone.violation" for a in got)
+        assert rec["geofence_fires"] == {"inside": 0, "outside": geo,
+                                         "enter": 0}
+        assert engine._metrics.counter(
+            "geofence.fires_outside").value == geo > 0
+
     def test_parity_under_pallas_interpret_geofence(self):
         """Lane compaction composes with every containment kernel the
         step can select — the interpret-mode pallas variant included."""

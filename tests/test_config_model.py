@@ -133,3 +133,35 @@ def test_nested_tenants_block_flagged():
         "tenants": {"acme": {"tenants": {"acme": {
             "pipeline": {"batch_size": 1}}}}}})
     assert any(i.path == "tenants.acme.tenants" for i in issues)
+
+
+def test_rule_and_lane_capacities_reach_the_engine(tmp_path):
+    """`pipeline.max_threshold_rules`, `pipeline.max_geofence_rules` and
+    `pipeline.alert_lane_capacity` of a serve config size the engine
+    `serve` builds; left out, they keep the defaults."""
+    from sitewhere_tpu.__main__ import _build_config, _build_instance
+
+    def engine_of(**pipeline):
+        cfg = _build_config(None)
+        cfg.set("persist.data_dir", str(tmp_path / f"d{len(pipeline)}"))
+        cfg.set("pipeline.max_devices", 256)
+        cfg.set("pipeline.batch_size", 64)
+        for key, value in pipeline.items():
+            cfg.set(f"pipeline.{key}", value)
+        return _build_instance(cfg).pipeline_engine
+
+    default = engine_of()
+    assert (default.max_threshold_rules, default.max_geofence_rules,
+            default.alert_lane_capacity) == (256, 256, 128)
+    sized = engine_of(max_threshold_rules=32, max_geofence_rules=4096,
+                      alert_lane_capacity=2048)
+    assert (sized.max_threshold_rules, sized.max_geofence_rules,
+            sized.alert_lane_capacity) == (32, 4096, 2048)
+
+
+def test_enter_is_a_geofence_condition_choice():
+    rules = [{"token": "g", "type": "geofence", "zone_token": "z",
+              "condition": "enter"}]
+    assert validate_config({"rules": rules}) == []
+    bad = validate_config({"rules": [dict(rules[0], condition="exit")]})
+    assert bad and bad[0].path.endswith("condition")

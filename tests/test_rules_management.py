@@ -76,6 +76,21 @@ class TestRuleRest:
         with pytest.raises(SiteWhereClientError):
             client.delete("/api/rules/dup")  # 404 after delete
 
+    def test_enter_condition_over_rest(self, rig):
+        _instance, _rest, client = rig
+        from sitewhere_tpu.client.rest import SiteWhereClientError
+
+        created = client.post("/api/rules", {
+            "type": "geofence", "token": "toll", "zone_token": "seg-1",
+            "condition": "enter", "alert_type": "toll.notification"})
+        assert created["condition"] == "enter"
+        assert client.get("/api/rules/toll")["condition"] == "enter"
+        with pytest.raises(SiteWhereClientError):
+            client.post("/api/rules", {
+                "type": "geofence", "token": "toll-2",
+                "zone_token": "seg-1", "condition": "exit"})
+        client.delete("/api/rules/toll")
+
     def test_admin_page_lists_rules_section(self, rig):
         _instance, rest, _client = rig
         import urllib.request

@@ -57,6 +57,19 @@ def test_pallas_geofence_compiles_for_v5e(one_chip, n_zones):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_pallas_geofence_compiles_for_v5e_enter_shape(one_chip):
+    """The `enter` condition's one call: a batch of 8,192 rows and their
+    previous points (16,384) against 4,096 rectangles (Linear Road's
+    4,000 segment zones in a 4,096-slot table)."""
+    from sitewhere_tpu.ops.pallas_geofence import points_in_zones_pallas
+
+    pts = jax.ShapeDtypeStruct((2 * 8192,), jnp.float32, sharding=one_chip)
+    verts = jax.ShapeDtypeStruct((4096, 4, 2), jnp.float32,
+                                 sharding=one_chip)
+    compiled = points_in_zones_pallas.lower(pts, pts, verts).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_fused_step_compiles_for_v5e(one_chip):
     """The fused single-chip step at `__graft_entry__.entry()` shapes."""
     from __graft_entry__ import entry
